@@ -23,6 +23,7 @@ from benchmarks import (bench_ablation, bench_adapter_memory,  # noqa: E402
                         bench_provisioning, bench_roofline,
                         bench_scale_instances, bench_scale_server,
                         bench_transport, common)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 ALL = [
     ("fig1a_adapter_memory", bench_adapter_memory.main),
@@ -132,6 +133,7 @@ def main(argv=None) -> None:
                     help="write captured rows as JSON (default "
                          "BENCH_smoke.json in --smoke mode)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     suite = SMOKE if args.smoke else \
         PROVISIONING if args.provisioning else \

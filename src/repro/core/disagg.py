@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ModelConfig
 from repro.models import layers as ll
 from repro.models import moe as moe_mod
@@ -93,6 +93,33 @@ def _ep_einsum(eq: str, a, w, mesh_ctx):
     out = mapped(jax.device_put(a, sh), jax.device_put(w, sh))
     # staticcheck: disable=SC006 (tracer-guarded eager branch, host plane)
     return jax.device_put(out, NamedSharding(mesh_ctx.mesh, P()))
+
+
+_PAGED_ATTN_CACHE: Dict = {}
+
+
+def _paged_attention(q, k_new, v_new, k_pool, v_pool, block_table, pos_vec,
+                     window: int, mesh_ctx):
+    """``ll.decode_attention_update_slots_paged``; under a mesh it runs as
+    one replicated copy per device inside a shard_map, because XLA cannot
+    partition a Mosaic kernel (the paged-attention kernel) by itself. The
+    map has no collectives, and each device computes exactly the
+    single-device program."""
+    if mesh_ctx is None:
+        return ll.decode_attention_update_slots_paged(
+            q, k_new, v_new, k_pool, v_pool, block_table, pos_vec,
+            window=window)
+    key = (mesh_ctx.mesh, window)
+    mapped = _PAGED_ATTN_CACHE.get(key)
+    if mapped is None:
+        def body(*args):
+            return ll.decode_attention_update_slots_paged(*args,
+                                                          window=window)
+
+        mapped = jax.jit(shard_map(body, mesh=mesh_ctx.mesh, in_specs=P(),
+                                   out_specs=P(), check_vma=False))
+        _PAGED_ATTN_CACHE[key] = mapped
+    return mapped(q, k_new, v_new, k_pool, v_pool, block_table, pos_vec)
 
 
 def _client_attn(x, lp, cfg, pos, k_c, v_c, positions):
@@ -205,9 +232,9 @@ def disagg_decode_step_slots(params, cfg: ModelConfig, k_cache, v_cache,
                 q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l], pos_vec,
                 window=cfg.sliding_window)
         else:
-            att, k_l, v_l = ll.decode_attention_update_slots_paged(
+            att, k_l, v_l = _paged_attention(
                 q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l],
-                block_table, pos_vec, window=cfg.sliding_window)
+                block_table, pos_vec, cfg.sliding_window, mesh_ctx)
         k_cache = k_cache.at[l].set(k_l)
         v_cache = v_cache.at[l].set(v_l)
         x = x + ll.out_project(att[:, None], lp["attn"])

@@ -17,7 +17,11 @@ Expert variant (MoE expert-specific adapters, paper Fig. 3b):
 
 VMEM budget per grid step: d_in*r + r*d_out + d_in + d_out floats — e.g.
 d=8192, r=64, d_out=8192: ~2.2 MB in bf16, well under the ~16 MB/core VMEM;
-block dims are 128-lane aligned via the ops.py padding wrapper.
+block dims are 128-lane aligned via the ops.py padding wrapper. Rows are
+laid out as (T, 1, d) so each grid step's x/out block is a whole (1, d)
+trailing slab: Mosaic requires a block's last two dims to be (8, 128)
+multiples or the array's own, and a (1, d) block of a (T, d) array is
+neither.
 """
 from __future__ import annotations
 
@@ -35,10 +39,10 @@ def _kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
 
     @pl.when(ids_ref[i] >= 0)
     def _():
-        h = jnp.dot(x_ref[...].astype(F32), a_ref[0].astype(F32),
+        h = jnp.dot(x_ref[0].astype(F32), a_ref[0].astype(F32),
                     preferred_element_type=F32)          # (1, r)
-        o_ref[...] = jnp.dot(h, b_ref[0].astype(F32),
-                             preferred_element_type=F32)  # (1, d_out)
+        o_ref[0] = jnp.dot(h, b_ref[0].astype(F32),
+                           preferred_element_type=F32)  # (1, d_out)
 
     @pl.when(ids_ref[i] < 0)
     def _():
@@ -55,19 +59,20 @@ def bgmv(x, A, B, ids, *, interpret: bool = True):
         num_scalar_prefetch=1,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, d_in), lambda i, ids: (i, 0)),
+            pl.BlockSpec((1, 1, d_in), lambda i, ids: (i, 0, 0)),
             pl.BlockSpec((1, d_in, r),
                          lambda i, ids: (jnp.maximum(ids[i], 0), 0, 0)),
             pl.BlockSpec((1, r, d_out),
                          lambda i, ids: (jnp.maximum(ids[i], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d_out), lambda i, ids: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_out), lambda i, ids: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, d_out), F32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, d_out), F32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), x, A, B)
+    )(ids.astype(jnp.int32), x.reshape(T, 1, d_in), A, B)
+    return out.reshape(T, d_out)
 
 
 def _kernel_ranked(ids_ref, ranks_ref, x_ref, a_ref, b_ref, o_ref):
@@ -75,14 +80,14 @@ def _kernel_ranked(ids_ref, ranks_ref, x_ref, a_ref, b_ref, o_ref):
 
     @pl.when(ids_ref[i] >= 0)
     def _():
-        h = jnp.dot(x_ref[...].astype(F32), a_ref[0].astype(F32),
+        h = jnp.dot(x_ref[0].astype(F32), a_ref[0].astype(F32),
                     preferred_element_type=F32)          # (1, r)
         # per-slot true rank: lanes past it are the pool's exact-zero
         # padding — force +0.0 so trimming stays bit-compatible
         col = jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
         h = jnp.where(col < ranks_ref[i], h, 0.0)
-        o_ref[...] = jnp.dot(h, b_ref[0].astype(F32),
-                             preferred_element_type=F32)  # (1, d_out)
+        o_ref[0] = jnp.dot(h, b_ref[0].astype(F32),
+                           preferred_element_type=F32)  # (1, d_out)
 
     @pl.when(ids_ref[i] < 0)
     def _():
@@ -103,7 +108,7 @@ def bgmv_ranked(x, A, B, ids, ranks, *, interpret: bool = True):
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, d_in), lambda i, ids, ranks: (i, 0)),
+            pl.BlockSpec((1, 1, d_in), lambda i, ids, ranks: (i, 0, 0)),
             pl.BlockSpec((1, d_in, r),
                          lambda i, ids, ranks: (jnp.maximum(ids[i], 0),
                                                 0, 0)),
@@ -111,13 +116,16 @@ def bgmv_ranked(x, A, B, ids, ranks, *, interpret: bool = True):
                          lambda i, ids, ranks: (jnp.maximum(ids[i], 0),
                                                 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d_out), lambda i, ids, ranks: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_out),
+                               lambda i, ids, ranks: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel_ranked, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, d_out), F32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, d_out), F32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), row_ranks.astype(jnp.int32), x, A, B)
+    )(ids.astype(jnp.int32), row_ranks.astype(jnp.int32),
+      x.reshape(T, 1, d_in), A, B)
+    return out.reshape(T, d_out)
 
 
 def _kernel_expert(ids_ref, eids_ref, x_ref, a_ref, b_ref, o_ref):
@@ -125,10 +133,10 @@ def _kernel_expert(ids_ref, eids_ref, x_ref, a_ref, b_ref, o_ref):
 
     @pl.when(ids_ref[i] >= 0)
     def _():
-        h = jnp.dot(x_ref[...].astype(F32), a_ref[0, 0].astype(F32),
+        h = jnp.dot(x_ref[0].astype(F32), a_ref[0, 0].astype(F32),
                     preferred_element_type=F32)
-        o_ref[...] = jnp.dot(h, b_ref[0, 0].astype(F32),
-                             preferred_element_type=F32)
+        o_ref[0] = jnp.dot(h, b_ref[0, 0].astype(F32),
+                           preferred_element_type=F32)
 
     @pl.when(ids_ref[i] < 0)
     def _():
@@ -144,7 +152,7 @@ def bgmv_expert(x, A, B, ids, eids, *, interpret: bool = True):
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, d_in), lambda i, ids, eids: (i, 0)),
+            pl.BlockSpec((1, 1, d_in), lambda i, ids, eids: (i, 0, 0)),
             pl.BlockSpec(
                 (1, 1, d_in, r),
                 lambda i, ids, eids: (jnp.maximum(ids[i], 0), eids[i], 0, 0)),
@@ -152,10 +160,12 @@ def bgmv_expert(x, A, B, ids, eids, *, interpret: bool = True):
                 (1, 1, r, d_out),
                 lambda i, ids, eids: (jnp.maximum(ids[i], 0), eids[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d_out), lambda i, ids, eids: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_out), lambda i, ids, eids: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel_expert, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, d_out), F32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, d_out), F32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), eids.astype(jnp.int32), x, A, B)
+    )(ids.astype(jnp.int32), eids.astype(jnp.int32), x.reshape(T, 1, d_in),
+      A, B)
+    return out.reshape(T, d_out)

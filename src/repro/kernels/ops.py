@@ -1,11 +1,11 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; elsewhere (this CPU container, and any
-test run) they execute in interpret mode, which runs the kernel body in
-Python per grid step — same math, same blocking. ``use_ref()`` can force the
-pure-jnp oracle (used by the model code on non-TPU backends where interpret
-mode would be needlessly slow inside big jits). REPRO_PALLAS_INTERPRET=1/0
-overrides the backend-derived interpret choice (see ``pallas_interpret``).
+On a TPU the kernels always compile natively through Mosaic: no setting
+routes a TPU run to interpret mode or to the ``ref.py`` oracles. Elsewhere
+the model code runs the pure-jnp oracles (interpret mode would be
+needlessly slow inside big jits); ``REPRO_USE_PALLAS=1`` runs the kernels
+instead, in interpret mode on the CPU, which executes the kernel body per
+grid step — same math, same blocking (the kernel tests set it).
 
 Padding: TPU lanes want the last dim % 128 == 0 and sublanes % 8 == 0; the
 wrappers zero-pad r / d_out / cap as needed and slice back.
@@ -32,26 +32,14 @@ def on_tpu() -> bool:
 
 
 def kernels_enabled() -> bool:
-    env = os.environ.get("REPRO_USE_PALLAS", "auto")
-    if env == "1":
-        return True
-    if env == "0":
-        return False
-    return on_tpu()
+    """Pallas kernels (not the ``ref.py`` oracles): always on a TPU; on
+    other backends only when ``REPRO_USE_PALLAS=1``."""
+    return on_tpu() or os.environ.get("REPRO_USE_PALLAS") == "1"
 
 
 def pallas_interpret() -> bool:
-    """Whether pallas_call should run in interpret mode. Default: native
-    compile on TPU, interpret elsewhere. REPRO_PALLAS_INTERPRET=1 forces
-    interpret even on TPU (kernel-body debugging); =0 forces native
-    lowering (e.g. to surface lowering errors under a CPU-emulated TPU
-    backend)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "auto")
-    if env == "1":
-        return True
-    if env == "0":
-        return False
-    return not on_tpu()
+    """Interpret mode is for the CPU backend only; a TPU compiles."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x, mult, axis):
@@ -170,8 +158,8 @@ def sgmv_rank_grouped(seg_rows, seg_adapter, seg_rank, A, B):
     sliced to that rank, so a rank-4 bucket prices rank-4 work instead of
     the pool rank. Feed it ``build_segments_ranked`` output (segments
     pre-sorted by rank so each bucket is a contiguous slice). Matches
-    ``sgmv_rank_grouped_ref`` exactly — bucket layout never changes the
-    math."""
+    ``sgmv_rank_grouped_ref`` to f32 rounding: a bucket contracts over its
+    sliced rank, which sums in a different order than the padded form."""
     if not kernels_enabled():
         return _ref.sgmv_rank_grouped_ref(seg_rows, seg_adapter, seg_rank,
                                           A, B)

@@ -42,7 +42,8 @@ def sgmv_ranked_ref(seg_rows, seg_adapter, seg_rank, A, B):
 
 def sgmv_rank_grouped_ref(seg_rows, seg_adapter, seg_rank, A, B):
     """Oracle for ops.sgmv_rank_grouped: the bucketed dispatch computes
-    exactly the true-rank-masked SGMV, whatever the bucket layout."""
+    the true-rank-masked SGMV, whatever the bucket layout (to f32
+    rounding: a bucket sums over its sliced rank)."""
     return sgmv_ranked_ref(seg_rows, seg_adapter, seg_rank, A, B)
 
 

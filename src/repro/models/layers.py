@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from repro.distributed.sharding import constrain, active_rules, mesh_axis_size
 
@@ -242,7 +243,6 @@ def causal_attention(q, k, v, *, causal: bool = True, window: int = 0,
         return out.reshape(B, Sq, H, hd)
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     n_model = mesh_axis_size(model_ax)
     H_loc = H // n_model
@@ -385,7 +385,6 @@ def decode_attention_update(q, k_new, v_new, k_cache, v_cache, pos, *,
         return (out.reshape(B, H, hd).astype(q.dtype), k_c, v_c, ks, vs, kp)
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     batch_axes = rules.spec(["batch"], [B])[0]
     kv_spec = P(batch_axes, axis, None, None)
@@ -533,7 +532,6 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
         return out.reshape(B, H, hd).astype(q.dtype)
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     n_shards = mesh_axis_size(axis)
     S_loc = S // n_shards
@@ -602,7 +600,6 @@ def mlp(x, p, cfg):
                          p["down"]).astype(x.dtype)
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     # weight at-rest specs (dim0/dim1 per param_specs: fsdp x model)
     def wspec(name, dim_ff):

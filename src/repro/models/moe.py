@@ -25,7 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.distributed.sharding import active_rules, mesh_axis_size
 

@@ -188,8 +188,16 @@ def test_build_segments_all_padding_marks_empty_adapters():
 # --------------------------- rank-aware kernels --------------------------- #
 # The mixed-rank invariant: pools prefix-zero every lane >= the adapter's
 # true rank, so bounding the compute at the true rank trims only exact-zero
-# work — every rank-aware variant must be BIT-identical to its padded twin
-# (assert_array_equal, not allclose).
+# work. Where the rank-aware variant and its padded twin are the same
+# program with a mask added, they are BIT-identical (assert_array_equal).
+# Where they are different programs (a Pallas kernel against an XLA einsum,
+# or a rank-sliced contraction against the padded one), the f32 sums run in
+# a different order and agree only to f32 rounding: _F32_REORDER.
+
+# Reassociating a length-n f32 dot changes it by at most ~n * 2^-24 of the
+# sum of |terms|; at n <= 128 that is < 1e-5 relative. atol covers outputs
+# that cancel to near zero.
+_F32_REORDER = dict(rtol=1e-5, atol=1e-6)
 
 def _mixed_rank_pool(key, N, d_in, r, d_out, ranks):
     A = np.asarray(jax.random.normal(jax.random.fold_in(key, 1),
@@ -204,8 +212,10 @@ def _mixed_rank_pool(key, N, d_in, r, d_out, ranks):
 
 def test_bgmv_ranked_bitwise_vs_padded():
     """bgmv_ranked masks the accumulator at each row's TRUE rank; on a
-    prefix-zeroed pool that is bit-identical to padded bgmv and to
-    bgmv_ranked_ref (incl. masked rows, ids < 0)."""
+    prefix-zeroed pool that is bit-identical to the padded bgmv kernel, and
+    bgmv_ranked_ref is bit-identical to bgmv_ref. Kernel against oracle is
+    two programs, so they agree to f32 reordering (incl. masked rows,
+    ids < 0, which are exact zeros in both)."""
     T, d, r, N = 24, 128, 32, 5
     ranks = np.asarray([4, 8, 16, 32, 8], np.int32)
     key = jax.random.PRNGKey(11)
@@ -215,9 +225,12 @@ def test_bgmv_ranked_bitwise_vs_padded():
     got = ops.bgmv_ranked(x, A, B, ids, jnp.asarray(ranks))
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(ops.bgmv(x, A, B, ids)))
-    np.testing.assert_array_equal(
-        np.asarray(got),
-        np.asarray(ref.bgmv_ranked_ref(x, A, B, ids, jnp.asarray(ranks))))
+    want = ref.bgmv_ranked_ref(x, A, B, ids, jnp.asarray(ranks))
+    np.testing.assert_array_equal(np.asarray(want),
+                                  np.asarray(ref.bgmv_ref(x, A, B, ids)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_F32_REORDER)
+    assert np.all(np.asarray(got)[np.asarray(ids) < 0] == 0.0)
 
 
 def test_sgmv_ranked_bitwise_vs_padded():
@@ -241,8 +254,11 @@ def test_sgmv_ranked_bitwise_vs_padded():
 
 def test_sgmv_rank_grouped_bitwise_vs_padded():
     """The rank-bucketed dispatch (one launch per distinct rank, A/B sliced
-    to the bucket rank) changes the work, never the math: bitwise equal to
-    padded sgmv and to sgmv_rank_grouped_ref."""
+    to the bucket rank) changes the work, not the math: its oracle
+    sgmv_rank_grouped_ref is bit-identical to sgmv_ref. The bucketed kernel
+    contracts over the sliced rank, a different f32 summation than the
+    padded one, so it agrees with padded sgmv and with its oracle to f32
+    reordering."""
     T, d, r, d_out, N, cap = 53, 128, 64, 64, 6, 8
     ranks = np.asarray([4, 8, 16, 64, 8, 4], np.int32)
     key = jax.random.PRNGKey(13)
@@ -252,11 +268,14 @@ def test_sgmv_rank_grouped_bitwise_vs_padded():
     segs, seg_ad, seg_rank, _ = ops.build_segments_ranked(
         x, row_ad, N, cap, ranks)
     got = ops.sgmv_rank_grouped(segs, seg_ad, seg_rank, A, B)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(ops.sgmv(segs, seg_ad, A, B)))
+    want = ref.sgmv_rank_grouped_ref(segs, seg_ad, seg_rank, A, B)
     np.testing.assert_array_equal(
-        np.asarray(got),
-        np.asarray(ref.sgmv_rank_grouped_ref(segs, seg_ad, seg_rank, A, B)))
+        np.asarray(want), np.asarray(ref.sgmv_ref(segs, seg_ad, A, B)))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ops.sgmv(segs, seg_ad, A, B)),
+                               **_F32_REORDER)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_F32_REORDER)
 
 
 def test_fused_sgmv_ranked_bitwise_vs_padded():
