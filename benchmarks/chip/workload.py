@@ -1,0 +1,49 @@
+"""Traffic: one data file per mix (``traffic/<name>.json``) read by the
+generator its ``kind`` names (``generators/<kind>.py``, a ``generate``
+function). A new mix of an existing kind is a new data file only."""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+from typing import Dict, List
+
+from spec import CHIP_DIR
+
+
+@dataclasses.dataclass
+class Request:
+    offset: float           # scheduled send time, seconds after window start
+    prompt: List[int]
+    output_len: int
+    adapter: int
+
+
+def load_traffic(name: str) -> Dict:
+    with open(CHIP_DIR / "traffic" / f"{name}.json") as f:
+        return json.load(f)
+
+
+def _generator(kind: str):
+    path = CHIP_DIR / "generators" / f"{kind}.py"
+    if not path.is_file():
+        raise ValueError(f"traffic kind {kind!r} has no generator at {path}")
+    spec = importlib.util.spec_from_file_location(f"generators.{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.generate
+
+
+def generate(traffic: Dict, *, rate: float, seconds: float, seed: int,
+             vocab: int, n_adapters: int) -> List[Request]:
+    """The window's requests, sorted by scheduled send time."""
+    reqs = _generator(traffic["kind"])(traffic, rate=rate, seconds=seconds,
+                                       seed=seed, vocab=vocab,
+                                       n_adapters=n_adapters)
+    return sorted(reqs, key=lambda r: r.offset)
+
+
+def longest_request(traffic: Dict) -> Dict[str, int]:
+    """The largest prompt and output the mix can produce."""
+    return {"prompt": int(traffic["prompt"]["max"]),
+            "output": int(traffic["output"]["max"])}
