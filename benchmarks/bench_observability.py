@@ -12,8 +12,9 @@ observability lane's artifact):
       tracing is observation, never perturbation.
   (b) FAITHFULNESS: a traced run exports the Perfetto trace
       (``trace_observability.json``, loadable at ui.perfetto.dev) and
-      the span set must cover >= 95% of every request's TTFT window
-      (``obs.ttft_coverage_min``), plus a populated Prometheus view.
+      the span set must cover >= 95% of every request's TTFT window,
+      timed apart on the caller's wall clock (``obs.ttft_coverage_min``),
+      plus a populated Prometheus view.
 """
 import dataclasses
 import json
@@ -21,6 +22,7 @@ import time
 
 from benchmarks.common import emit
 from repro.configs import get_config
+from repro.obs import wall_time
 from repro.serving.api import ServeConfig, build_system
 
 ROUNDS = 5
@@ -97,25 +99,40 @@ def trace_plane():
                      trace=True)
     system = build_system(sc, cfg, params=params, pool=pool)
     hs = system.submit_workload(_reqs())
-    system.drain()
+    # each TTFT window on this caller's own reading of the cluster
+    # plane's wall clock, apart from the spans: from the start of the round that enqueued the request to the
+    # moment its first token reached the handle
+    first_token, round_start = {}, {}
+    for h in hs:
+        h.on_token(lambda h, tok: first_token.setdefault(h.rid, wall_time()))
+    while not system.backend.idle():
+        t = wall_time()
+        for ev in system.step():
+            if ev.kind == "queued":
+                round_start[ev.rid] = t
     assert all(h.state.name == "FINISHED" for h in hs)
     obs = system.observability()
     obs.write_trace(TRACE_PATH)
     doc = obs.perfetto()
     emit("obs.trace_events", len(doc["traceEvents"]),
          f"perfetto JSON -> {TRACE_PATH}")
-    # span coverage of each request's TTFT window (arrival -> first token):
-    # the queued+prefill stage spans must account for >= 95% of it
-    cov_min = 1.0
+    # the queued+prefill stage spans must account for >= 95% of each
+    # window, and the ttft_seconds histogram must agree with the windows
+    cov_min, windows = 1.0, 0.0
     for h in hs:
-        ttft = h.request.first_token - h.request.arrival
-        track = f"req:{h.rid}"
-        covered = sum(s.duration for s in system.tracer.spans_for(track)
-                      if s.name in ("queued", "prefill"))
-        cov_min = min(cov_min, covered / max(ttft, 1e-9))
+        stages = {s.name: s for s in system.tracer.spans_for(f"req:{h.rid}")}
+        window = first_token[h.rid] - round_start[h.rid]
+        covered = stages["queued"].duration + stages["prefill"].duration
+        assert covered <= window, "stage spans outlast the TTFT window"
+        cov_min = min(cov_min, covered / max(window, 1e-9))
+        windows += window
     emit("obs.ttft_coverage_min", round(cov_min, 4),
          "min over requests of span coverage of the TTFT window")
     assert cov_min >= 0.95, "spans must cover >= 95% of every TTFT window"
+    ttft = obs.registry.get("ttft_seconds")
+    assert ttft.count == len(hs)
+    assert 0.95 * windows <= ttft.sum <= windows, \
+        "ttft_seconds disagrees with the measured TTFT windows"
     prom = obs.prometheus()
     n_metrics = sum(1 for ln in prom.splitlines()
                     if ln.startswith("# TYPE"))

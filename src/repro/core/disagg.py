@@ -153,48 +153,54 @@ def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
     expert GEMMs run expert-parallel over the mesh (see ``_ep_einsum``)."""
     B = x.shape[0]
     E, K = cfg.n_experts, cfg.top_k
-    h = ll.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    xf = h.reshape(-1, cfg.d_model)
-    T = xf.shape[0]
-    ids, wts = moe_mod.route(xf, lp["moe"]["router"], E, K)
-    # same dropless threshold as the coupled path (_moe_local): the two
-    # paths must drop (or not drop) identically at EVERY batch size, else
-    # the coupled==disagg token equality breaks on huge decode buckets
-    C = moe_mod.capacity(T, K, E, cfg.capacity_factor,
-                         dropless=(T * K <= 4096))
-    xe, slot_tok = moe_mod.local_dispatch(xf, ids, C, E)  # (E, C, d)
-    rows = xe.reshape(E * C, cfg.d_model)
-    row_expert = (jnp.arange(E * C, dtype=jnp.int32) // C)
-    tok_safe = jnp.minimum(slot_tok, T - 1)
-    row_adapter = jnp.where(slot_tok < T,
-                            jnp.asarray(adapter_ids)[tok_safe], -1)
+    with jax.named_scope("moe_router"):
+        h = ll.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        xf = h.reshape(-1, cfg.d_model)
+        T = xf.shape[0]
+        ids, wts = moe_mod.route(xf, lp["moe"]["router"], E, K)
+        # same dropless threshold as the coupled path (_moe_local): the
+        # two paths must drop (or not drop) identically at EVERY batch
+        # size, else the coupled==disagg token equality breaks on huge
+        # decode buckets
+        C = moe_mod.capacity(T, K, E, cfg.capacity_factor,
+                             dropless=(T * K <= 4096))
+        xe, slot_tok = moe_mod.local_dispatch(xf, ids, C, E)  # (E, C, d)
+        rows = xe.reshape(E * C, cfg.d_model)
+        row_expert = (jnp.arange(E * C, dtype=jnp.int32) // C)
+        tok_safe = jnp.minimum(slot_tok, T - 1)
+        row_adapter = jnp.where(slot_tok < T,
+                                jnp.asarray(adapter_ids)[tok_safe], -1)
 
     # hook 1: up/gate — client GEMM + server delta (overlapped on HW)
     mp = lp["moe"]
-    g = _ep_einsum("ecd,edf->ecf", xe, mp["gate"], mesh_ctx)
-    u = _ep_einsum("ecd,edf->ecf", xe, mp["up"], mesh_ctx)
-    d_up = server.compute("up", l, rows, row_adapter, row_expert)
-    d_up = _replicate_eager(d_up, mesh_ctx)
-    d_up = d_up.reshape(E, C, -1) * lora_scale
-    dg, du = jnp.split(d_up, 2, axis=-1)
-    act = (jax.nn.silu(g + dg) * (u + du)).astype(x.dtype)
-
-    # hook 2: down
-    y = _ep_einsum("ecf,efd->ecd", act, mp["down"], mesh_ctx)
-    d_dn = server.compute("down", l, act.reshape(E * C, -1),
-                          row_adapter, row_expert)
-    d_dn = _replicate_eager(d_dn, mesh_ctx)
-    y = y + d_dn.reshape(E, C, -1) * lora_scale
+    with jax.named_scope("moe_experts"):
+        g = _ep_einsum("ecd,edf->ecf", xe, mp["gate"], mesh_ctx)
+        u = _ep_einsum("ecd,edf->ecf", xe, mp["up"], mesh_ctx)
+    with jax.named_scope("lora_hook"):
+        d_up = server.compute("up", l, rows, row_adapter, row_expert)
+        d_up = _replicate_eager(d_up, mesh_ctx)
+        d_up = d_up.reshape(E, C, -1) * lora_scale
+        dg, du = jnp.split(d_up, 2, axis=-1)
+    with jax.named_scope("moe_experts"):
+        act = (jax.nn.silu(g + dg) * (u + du)).astype(x.dtype)
+        # hook 2: down
+        y = _ep_einsum("ecf,efd->ecd", act, mp["down"], mesh_ctx)
+    with jax.named_scope("lora_hook"):
+        d_dn = server.compute("down", l, act.reshape(E * C, -1),
+                              row_adapter, row_expert)
+        d_dn = _replicate_eager(d_dn, mesh_ctx)
+        y = y + d_dn.reshape(E, C, -1) * lora_scale
 
     # combine with router weights (same bookkeeping as the coupled path)
-    slot_expert = jnp.arange(E * C, dtype=jnp.int32) // C
-    match = ids[tok_safe] == slot_expert[:, None]
-    w_slot = jnp.where(slot_tok < T,
-                       jnp.sum(jnp.where(match, wts[tok_safe], 0.0), -1),
-                       0.0)
-    out = jnp.zeros((T + 1, cfg.d_model), F32)
-    out = out.at[slot_tok].add(y.reshape(E * C, -1) * w_slot[:, None])
-    return x + out[:T].reshape(B, 1, cfg.d_model).astype(x.dtype)
+    with jax.named_scope("moe_router"):
+        slot_expert = jnp.arange(E * C, dtype=jnp.int32) // C
+        match = ids[tok_safe] == slot_expert[:, None]
+        w_slot = jnp.where(slot_tok < T,
+                           jnp.sum(jnp.where(match, wts[tok_safe], 0.0), -1),
+                           0.0)
+        out = jnp.zeros((T + 1, cfg.d_model), F32)
+        out = out.at[slot_tok].add(y.reshape(E * C, -1) * w_slot[:, None])
+        return x + out[:T].reshape(B, 1, cfg.d_model).astype(x.dtype)
 
 
 def disagg_decode_step_slots(params, cfg: ModelConfig, k_cache, v_cache,
@@ -206,8 +212,11 @@ def disagg_decode_step_slots(params, cfg: ModelConfig, k_cache, v_cache,
     The slot-engine twin of ``transformer.decode_step_slots``: identical
     client math (embed -> attn -> MoE base GEMMs), with the LoRA deltas
     computed by the remote ``server`` at the two MoE hook points instead of
-    in-model. tokens: (B, 1); pos_vec: (B,) int32 (-1 = inactive slot, its
-    adapter id must be -1 too so the server contributes zero delta);
+    in-model. Its regions carry stable ``jax.named_scope`` names —
+    ``attention``, ``moe_router``, ``moe_experts``, ``lora_hook`` and
+    ``lm_head`` — which a compiled program keeps in each instruction's
+    metadata. tokens: (B, 1); pos_vec: (B,) int32 (-1 = inactive slot,
+    its adapter id must be -1 too so the server contributes zero delta);
     k_cache/v_cache: (L, B, S, KV, hd) — or paged pools
     (L, n_pages, page_size, KV, hd) when ``block_table`` (B, nb) is given,
     mirroring the coupled slot step. ``mesh_ctx`` (a
@@ -223,26 +232,28 @@ def disagg_decode_step_slots(params, cfg: ModelConfig, k_cache, v_cache,
 
     for l in range(cfg.n_layers):
         lp = _layer_params(params, l)
-        h = ll.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = ll.qkv_project(h, lp["attn"], cfg)
-        q = ll.apply_rope(q, positions, cfg.rope_theta)
-        k = ll.apply_rope(k, positions, cfg.rope_theta)
-        if block_table is None:
-            att, k_l, v_l = ll.decode_attention_update_slots(
-                q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l], pos_vec,
-                window=cfg.sliding_window)
-        else:
-            att, k_l, v_l = _paged_attention(
-                q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l],
-                block_table, pos_vec, cfg.sliding_window, mesh_ctx)
-        k_cache = k_cache.at[l].set(k_l)
-        v_cache = v_cache.at[l].set(v_l)
-        x = x + ll.out_project(att[:, None], lp["attn"])
+        with jax.named_scope("attention"):
+            h = ll.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = ll.qkv_project(h, lp["attn"], cfg)
+            q = ll.apply_rope(q, positions, cfg.rope_theta)
+            k = ll.apply_rope(k, positions, cfg.rope_theta)
+            if block_table is None:
+                att, k_l, v_l = ll.decode_attention_update_slots(
+                    q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l],
+                    pos_vec, window=cfg.sliding_window)
+            else:
+                att, k_l, v_l = _paged_attention(
+                    q[:, 0], k[:, 0], v[:, 0], k_cache[l], v_cache[l],
+                    block_table, pos_vec, cfg.sliding_window, mesh_ctx)
+            k_cache = k_cache.at[l].set(k_l)
+            v_cache = v_cache.at[l].set(v_l)
+            x = x + ll.out_project(att[:, None], lp["attn"])
         x = _moe_hooks_layer(x, lp, cfg, l, server, adapter_ids, lora_scale,
                              mesh_ctx=mesh_ctx)
 
-    x = ll.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = ll.unembed(x, params.get("lm_head", params["embed"]))
+    with jax.named_scope("lm_head"):
+        x = ll.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = ll.unembed(x, params.get("lm_head", params["embed"]))
     return logits[:, 0], k_cache, v_cache
 
 

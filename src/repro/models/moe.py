@@ -95,10 +95,11 @@ def expert_ffn(xe, wg, wu, wd, gated: bool = True, lora=None,
         if lora is None or name not in lora:
             return None
         from repro.kernels import ops
-        row_e = expert_offset + jnp.arange(E * C, dtype=jnp.int32) // C
-        return ops.bgmv_expert(
-            rows_in.reshape(E * C, -1), lora[name]["A"], lora[name]["B"],
-            row_adapter, row_e).reshape(E, C, -1) * lora_scale
+        with jax.named_scope("lora_hook"):
+            row_e = expert_offset + jnp.arange(E * C, dtype=jnp.int32) // C
+            return ops.bgmv_expert(
+                rows_in.reshape(E * C, -1), lora[name]["A"], lora[name]["B"],
+                row_adapter, row_e).reshape(E, C, -1) * lora_scale
 
     if gated:
         g = jnp.einsum("ecd,edf->ecf", xe, wg, preferred_element_type=F32)
@@ -208,7 +209,8 @@ def _moe_local(x, params, cfg, lora=None, ids_tok=None, lora_scale=1.0):
     B, S, d = x.shape
     xf = x.reshape(-1, d)
     T = xf.shape[0]
-    ids, wts = route(xf, params["router"], cfg.n_experts, cfg.top_k)
+    with jax.named_scope("moe_router"):
+        ids, wts = route(xf, params["router"], cfg.n_experts, cfg.top_k)
     C = capacity(T, cfg.top_k, cfg.n_experts, cfg.capacity_factor,
                  dropless=(T * cfg.top_k <= 4096))
     y = _dispatch_compute_combine(xf, ids, wts, params["gate"], params["up"],
@@ -227,7 +229,8 @@ def _dispatch_compute_combine(xf, ids, wts, wg, wu, wd, cfg, C,
     """
     T, d = xf.shape
     E = cfg.n_experts
-    xe, slot_tok = local_dispatch(xf, ids, C, E)  # (E, C, d)
+    with jax.named_scope("moe_router"):
+        xe, slot_tok = local_dispatch(xf, ids, C, E)  # (E, C, d)
 
     row_adapter = None
     if lora is not None and token_ads is not None:
@@ -248,9 +251,10 @@ def _dispatch_compute_combine(xf, ids, wts, wg, wu, wd, cfg, C,
             row_adapter = ra.reshape(-1)
         expert_offset = jax.lax.axis_index(ep_axis) * E_loc
 
-    y_e = expert_ffn(xe, wg, wu, wd, cfg.gated_mlp, lora=lora,
-                     row_adapter=row_adapter, expert_offset=expert_offset,
-                     lora_scale=lora_scale)
+    with jax.named_scope("moe_experts"):
+        y_e = expert_ffn(xe, wg, wu, wd, cfg.gated_mlp, lora=lora,
+                         row_adapter=row_adapter,
+                         expert_offset=expert_offset, lora_scale=lora_scale)
     if ff_axis is not None:
         y_e = jax.lax.psum(y_e, ff_axis)
 
@@ -260,17 +264,19 @@ def _dispatch_compute_combine(xf, ids, wts, wg, wu, wd, cfg, C,
                                  tiled=True)
 
     # combine with router weights: weight per slot via gather from (T,K)
-    y_slots = y_e.reshape(-1, d)
-    out = jnp.zeros((T + 1, d), F32)
-    # recover per-slot weights: slot_tok gives token; match expert of slot
-    slot_expert = jnp.arange(slot_tok.shape[0]) // C
-    tok_safe = jnp.minimum(slot_tok, T - 1)
-    match = ids[tok_safe] == slot_expert[:, None]  # (E*C, K)
-    w_slot = jnp.where(slot_tok < T,
-                       jnp.sum(jnp.where(match, wts[tok_safe], 0.0), axis=-1),
-                       0.0)
-    out = out.at[slot_tok].add(y_slots.astype(F32) * w_slot[:, None])
-    return out[:T]
+    with jax.named_scope("moe_router"):
+        y_slots = y_e.reshape(-1, d)
+        out = jnp.zeros((T + 1, d), F32)
+        # recover per-slot weights: slot_tok gives token; match expert of
+        # slot
+        slot_expert = jnp.arange(slot_tok.shape[0]) // C
+        tok_safe = jnp.minimum(slot_tok, T - 1)
+        match = ids[tok_safe] == slot_expert[:, None]  # (E*C, K)
+        w_slot = jnp.where(
+            slot_tok < T,
+            jnp.sum(jnp.where(match, wts[tok_safe], 0.0), axis=-1), 0.0)
+        out = out.at[slot_tok].add(y_slots.astype(F32) * w_slot[:, None])
+        return out[:T]
 
 
 def _moe_sharded(x, params, cfg, plan: MoEPlan, kind: str, lora=None,

@@ -335,7 +335,8 @@ def prefill_chunk(params, cfg, tokens, k_ctx, v_ctx):
     exactly the first S_ctx positions). LoRA-free, like all prefill here
     (paper footnote 1: prefill runs on separate LoRA-free instances under
     PD disaggregation). dense/moe/vlm only. No lm-head (admission needs
-    only the KV).
+    only the KV). Named scopes as in the disaggregated decode step:
+    ``attention`` here, ``moe_router``/``moe_experts`` in the MoE block.
 
     Returns (k_chunk, v_chunk), each (L, B, C, KV, hd).
     """
@@ -350,15 +351,17 @@ def prefill_chunk(params, cfg, tokens, k_ctx, v_ctx):
 
     def body(x, xs):
         lp, kc, vc = xs
-        h = ll.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = ll.qkv_project(h, lp["attn"], cfg)
-        q = ll.apply_rope(q, positions, cfg.rope_theta)
-        k = ll.apply_rope(k, positions, cfg.rope_theta)
-        k_full = jnp.concatenate([kc.astype(k.dtype), k], axis=1)
-        v_full = jnp.concatenate([vc.astype(v.dtype), v], axis=1)
-        attn = ll.causal_attention(q, k_full, v_full, causal=True,
-                                   window=cfg.sliding_window, q_offset=pos0)
-        x = x + ll.out_project(attn, lp["attn"])
+        with jax.named_scope("attention"):
+            h = ll.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = ll.qkv_project(h, lp["attn"], cfg)
+            q = ll.apply_rope(q, positions, cfg.rope_theta)
+            k = ll.apply_rope(k, positions, cfg.rope_theta)
+            k_full = jnp.concatenate([kc.astype(k.dtype), k], axis=1)
+            v_full = jnp.concatenate([vc.astype(v.dtype), v], axis=1)
+            attn = ll.causal_attention(q, k_full, v_full, causal=True,
+                                       window=cfg.sliding_window,
+                                       q_offset=pos0)
+            x = x + ll.out_project(attn, lp["attn"])
         h = ll.rms_norm(x, lp["ln2"], cfg.norm_eps)
         if cfg.is_moe:
             y = moe_mod.moe_block(h, lp["moe"], cfg, kind="decode")

@@ -3,10 +3,11 @@ and exporters (Chrome/Perfetto trace JSON, Prometheus text, JSONL).
 
 Design contract (pinned by tests/test_obs.py):
 
-  - ONE ``Tracer`` protocol serves BOTH execution planes. The cluster
-    plane records spans in its virtual round clock (wall-clock only as
-    span *attributes*); the sim plane records them in discrete-event
-    virtual time. Exporters never care which plane produced the trace.
+  - ONE ``Tracer`` protocol serves BOTH execution planes, each on one
+    clock: the cluster plane stamps the wall clock (``wall_time``, the
+    profiler-aligned ``perf_counter``; its ``scope`` spans also enter a
+    profiler annotation), the sim plane its discrete-event virtual
+    time. Exporters never care which plane produced the trace.
   - ``NULL_TRACER`` is the zero-cost default: every hot path guards on
     ``tracer.enabled`` before building span arguments, and the no-op
     methods themselves allocate nothing.

@@ -10,8 +10,11 @@ attribution is computed by identical code regardless of plane:
 
 Together the three cover a request's full TTFT window (queue wait +
 staging/prefill) plus its decode tail; child spans (adapter loads, KV
-allocation, per-instance decode steps) are recorded deeper in the
-stack by the cluster/simulator/cache layers onto the same tracer.
+allocation, decode steps, the cluster plane's ``serve.*`` scopes) are
+recorded deeper in the stack by the cluster/simulator/cache layers onto
+the same tracer. Each event is stamped on its plane's clock: its
+``wall`` time on the cluster plane, its virtual ``time`` on the sim
+plane (where ``wall`` is None).
 
 ``Observability`` is the user-facing facade returned by
 ``ServeSystem.observability()``: it bundles the tracer + registry with
@@ -73,7 +76,8 @@ class ObservabilityHub:
 
     def on_event(self, ev) -> None:
         """Consume one front-door ``Event`` (any plane)."""
-        tr, t, rid, kind = self.tracer, ev.time, ev.rid, ev.kind
+        tr, rid, kind = self.tracer, ev.rid, ev.kind
+        t = ev.time if ev.wall is None else ev.wall
         if kind.startswith("scale"):
             if ev.detail is not None:
                 tr.instant("control", kind, t, reason=ev.detail)
@@ -147,9 +151,13 @@ class Observability:
     """Facade over a serving system's tracer + registry + exporters
     (returned by ``ServeSystem.observability()``)."""
 
-    def __init__(self, hub: ObservabilityHub, backend):
+    def __init__(self, hub: ObservabilityHub, backend, clock=None):
         self._hub = hub
         self._backend = backend
+        # the plane's clock, for closing in-flight spans: the backend's
+        # virtual ``now`` unless the plane stamps another clock
+        self._clock = clock if clock is not None \
+            else (lambda: self._backend.now)
 
     @property
     def tracer(self) -> Tracer:
@@ -187,7 +195,7 @@ class Observability:
 
     def _finalize(self) -> None:
         if self._hub.tracer.enabled:
-            self._hub.tracer.finish(self._backend.now)
+            self._hub.tracer.finish(self._clock())
 
     def perfetto(self) -> Dict:
         """The trace as a Chrome/Perfetto trace-event dict (in-flight

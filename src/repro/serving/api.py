@@ -47,8 +47,11 @@ import itertools
 from typing import Callable, Dict, Iterator, List, Optional, Protocol, \
     Sequence, Tuple
 
+import jax
+
 from repro.configs.base import ModelConfig
 from repro.core.cost_model import Hardware, V5E
+from repro.obs.clock import wall_time
 from repro.obs.hub import Observability, ObservabilityHub
 from repro.obs.trace import NULL_TRACER, TimelineTracer
 from repro.serving import metrics
@@ -100,6 +103,9 @@ class Event:
     #                              |scale:<action> (autoscaler, rid=-1)
     token: Optional[int] = None  # real token id (cluster) / None (sim)
     detail: Optional[str] = None  # scale events: the autoscaler's reason
+    # wall-clock stamp (obs.clock.wall_time) of the step that produced
+    # the event: set on the cluster plane while tracing, else None
+    wall: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,11 +209,11 @@ class ServeConfig:
     rank_aware: bool = True
     adapter_ranks: Optional[Tuple[int, ...]] = None
     # observability (repro.obs): True records per-request spans (queued/
-    # prefill/decode + adapter-load, KV-alloc, store-prefetch and
-    # decode-step children) on a TimelineTracer and feeds the metrics
-    # registry — export via ServeSystem.observability(). False (default)
-    # wires the zero-cost NullTracer: bitwise-identical tokens either
-    # way, pinned by test.
+    # prefill/decode + adapter-load, KV-alloc, store-prefetch and, on
+    # the cluster plane, the serve.* host scopes on the wall clock) on a
+    # TimelineTracer and feeds the metrics registry — export via
+    # ServeSystem.observability(). False (default) wires the zero-cost
+    # NullTracer: bitwise-identical tokens either way, pinned by test.
     trace: bool = False
 
     def __post_init__(self):
@@ -473,7 +479,8 @@ class ClusterBackend:
             self._cancels.append((at, rid))
             return []
         if self.cluster.cancel(rid):
-            return [Event(now, rid, "cancelled")]
+            wall = wall_time() if self.cluster.tracer.enabled else None
+            return [Event(now, rid, "cancelled", wall=wall)]
         return []
 
     def step(self) -> List[Event]:
@@ -489,15 +496,21 @@ class ClusterBackend:
         for t, rid in due:
             evs.extend(self.cancel(rid))
         rep = self.cluster.step_round()
-        evs.extend(Event(rep["now"], -1, f"scale:{a.kind}", detail=a.reason)
-                   for a in rep["scale"])
-        evs.extend(Event(rep["now"], r.rid, "queued")
+        # wall stamps (tracing only): where in the round each event's
+        # moment fell — control, enqueue, the request's own prefill, and
+        # the end of the round's decode steps
+        w = rep.get("wall") or {}
+        w_admit = w.get("admit", {})
+        w_end = w.get("end")
+        evs.extend(Event(rep["now"], -1, f"scale:{a.kind}", detail=a.reason,
+                         wall=w.get("control")) for a in rep["scale"])
+        evs.extend(Event(rep["now"], r.rid, "queued", wall=w.get("enqueue"))
                    for r in rep["enqueued"])
-        evs.extend(Event(rep["now"], r.rid, "prefill")
-                   for r in rep["admitted"])
-        evs.extend(Event(rep["step_end"], rid, "token", token=tok)
-                   for rid, tok in rep["tokens"].items())
-        evs.extend(Event(rep["step_end"], r.rid, "finished")
+        evs.extend(Event(rep["now"], r.rid, "prefill",
+                         wall=w_admit.get(r.rid)) for r in rep["admitted"])
+        evs.extend(Event(rep["step_end"], rid, "token", token=tok,
+                         wall=w_end) for rid, tok in rep["tokens"].items())
+        evs.extend(Event(rep["step_end"], r.rid, "finished", wall=w_end)
                    for r in rep["finished"])
         return evs
 
@@ -655,7 +668,18 @@ class ServeSystem:
         # event stream into request-stage spans + the metrics registry.
         # trace=False wires the zero-cost NULL_TRACER and the hub is
         # never driven.
-        self.tracer = TimelineTracer() if cfg.trace else NULL_TRACER
+        # The cluster plane stamps the wall clock: its scope spans enter
+        # jax.profiler.TraceAnnotation (so they land on a profile's host
+        # plane) and Python garbage collections become serve.gc spans.
+        # The sim plane keeps its virtual clock.
+        real = cfg.backend == "cluster"
+        if cfg.trace:
+            self.tracer = TimelineTracer(
+                annotate=jax.profiler.TraceAnnotation if real else None,
+                gc_spans=real)
+        else:
+            self.tracer = NULL_TRACER
+        self._clock = wall_time if real else None
         self._hub = ObservabilityHub(self.tracer)
         if cfg.backend == "sim":
             self.backend: Backend = SimBackend(model, cfg,
@@ -762,15 +786,16 @@ class ServeSystem:
         ``scale_events`` shim."""
         evs = self.backend.step()
         traced = self.tracer.enabled
-        for ev in evs:
-            if traced:
-                self._hub.on_event(ev)
-            if ev.kind.startswith("scale"):
-                self.scale_events.append(ev)
-                continue
-            h = self.handles.get(ev.rid)
-            if h is not None:
-                h._apply(ev)
+        with self.tracer.scope("serve.events"):
+            for ev in evs:
+                if traced:
+                    self._hub.on_event(ev)
+                if ev.kind.startswith("scale"):
+                    self.scale_events.append(ev)
+                    continue
+                h = self.handles.get(ev.rid)
+                if h is not None:
+                    h._apply(ev)
         return evs
 
     def drain(self) -> None:
@@ -813,8 +838,10 @@ class ServeSystem:
 
     def close(self) -> None:
         """Tear down backend resources (the adapter store's prefetch
-        thread and owned disk-tier tempdir). Idempotent."""
+        thread and owned disk-tier tempdir) and stop the tracer's
+        garbage-collection hook. Idempotent."""
         self.backend.close()
+        self.tracer.close()
 
     # ---------------------------- metrics ----------------------------- #
     def kv_stats(self) -> Dict:
@@ -869,7 +896,7 @@ class ServeSystem:
         Perfetto/Prometheus/JSONL exporters. Always available — with
         ``trace=False`` the tracer is the NullTracer and only the
         pull-refreshed registry carries data."""
-        return Observability(self._hub, self.backend)
+        return Observability(self._hub, self.backend, clock=self._clock)
 
 
 def build_system(cfg: ServeConfig, model: ModelConfig, *, params=None,

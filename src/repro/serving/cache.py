@@ -39,11 +39,14 @@ class LoRACache:
                  prefetch: bool = True,
                  load_seconds_fn: Optional[Callable[[int, float],
                                            float]] = None,
-                 tracer: Optional[Tracer] = None):
+                 tracer: Optional[Tracer] = None,
+                 clock: Optional[Callable[[], float]] = None):
         self.capacity = capacity
-        # adapter-staging spans land on the owning plane's tracer; the
-        # timestamps are whatever virtual clock the caller passes as `now`
+        # adapter-staging spans land on the owning plane's tracer, on that
+        # plane's clock: ``clock()`` where given (the cluster plane's wall
+        # clock), else the virtual ``now`` the caller passes
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.clock = clock
         self.adapter_bytes = adapter_bytes
         self.n_layers = max(n_layers, 1)
         self.host_bw = host_bw
@@ -186,9 +189,10 @@ class LoRACache:
         if self.tracer.enabled:
             # the staging interval [admit, full residency]; first_ready
             # rides along so TTFT attribution can see the pipelined edge
+            t = now if self.clock is None else self.clock()
             self.tracer.span("adapter", f"adapter.load a{adapter_id}",
-                             now, now + t_full, adapter_id=adapter_id,
-                             first_ready=now + t_first)
+                             t, t + t_full, adapter_id=adapter_id,
+                             first_ready=t + t_first)
         r = ResidentAdapter(adapter_id, now, now + t_first, now + t_full, now)
         self.resident[adapter_id] = r
         self.dirty.add(adapter_id)

@@ -47,7 +47,7 @@ from repro.core.adapter import AdapterPool
 from repro.core.lora_server import LoRAServer
 from repro.models.cache import pages_for
 from repro.obs.clock import wall_time
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NO_SCOPE, NULL_TRACER, Tracer
 from repro.serving.autoscaler import Autoscaler, AutoscalePolicy, \
     ScaleAction, converge_replicas, pick_drain_candidate
 from repro.serving.cache import LoRACache
@@ -128,8 +128,10 @@ class Cluster:
                  server_pool: Optional[ServerPool] = None,
                  server: Optional[LoRAServer] = None,
                  tracer: Optional[Tracer] = None):
-        # span tracer (repro.obs): virtual round-clock timestamps, wall
-        # clock only as span attributes. NULL_TRACER = record nothing.
+        # span tracer (repro.obs): this plane stamps the WALL clock
+        # (obs.clock.wall_time) — serve.* scopes around each phase of a
+        # round, instants, counters and adapter loads alike.
+        # NULL_TRACER = record nothing.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.mesh_ctx = None
         if ccfg.mesh_shape is not None:
@@ -201,7 +203,8 @@ class Cluster:
         if ccfg.disaggregated:
             self.transport = make_transport(ccfg.transport, self.server_pool,
                                             n_adapters=pool.n,
-                                            mesh_ctx=self.mesh_ctx)
+                                            mesh_ctx=self.mesh_ctx,
+                                            tracer=self.tracer)
         self._ecfg = EngineConfig(max_len=ccfg.max_len, n_slots=ccfg.n_slots,
                                   paged=ccfg.paged, page_size=ccfg.page_size,
                                   n_pages=ccfg.n_pages,
@@ -227,7 +230,7 @@ class Cluster:
         return Engine(self.cfg, self.params, self._ecfg, pool=self.pool,
                       server=self.server_pool,
                       transport=self.transport or "host",
-                      mesh_ctx=self.mesh_ctx)
+                      mesh_ctx=self.mesh_ctx, tracer=self.tracer)
 
     def _pool_capacity(self) -> int:
         """The server pool's physical cache-slot bound: aggregate capacity
@@ -383,7 +386,7 @@ class Cluster:
                          prefetch=self.ccfg.prefetch_on,
                          load_seconds_fn=self.store.load_seconds
                          if self.store is not None else None,
-                         tracer=self.tracer)
+                         tracer=self.tracer, clock=wall_time)
 
     @property
     def now(self) -> float:
@@ -553,50 +556,81 @@ class Cluster:
         instance, retire finishers and fully-drained instances. Returns the
         round report: {"now", "step_end", "enqueued", "admitted", "tokens":
         {rid: tok}, "finished", "scale", "idle"} — the per-round token
-        stream the front door streams from."""
+        stream the front door streams from — and, while tracing, "wall":
+        the wall-clock moments the front door stamps its events with
+        ({"control", "enqueue", "admit": {rid: t}, "end"}).
+
+        With tracing on, each phase is a ``serve.*`` scope on the wall
+        clock: ``serve.round`` around ``serve.control``,
+        ``serve.enqueue``, ``serve.admit`` (one ``serve.prefill`` per
+        request, ``serve.residency_sync``), one ``serve.engine.step`` per
+        busy instance and ``serve.complete``."""
+        tr = self.tracer
+        with tr.scope("serve.round", round=self.rnd) if tr.enabled \
+                else NO_SCOPE:
+            return self._step_round()
+
+    def _step_round(self) -> Dict:
         ccfg = self.ccfg
+        tr = self.tracer
+        traced = tr.enabled
+        wall: Optional[Dict] = {"admit": {}} if traced else None
         now = self.now
-        if self.store is not None:
-            # land async-staged adapters at the round boundary, BEFORE any
-            # sync this round consumes them (main thread only)
-            self.store.drain_prefetched()
-        scale_actions = self._run_control(now)
+        with tr.scope("serve.control"):
+            if traced:
+                wall["control"] = wall_time()
+            if self.store is not None:
+                # land async-staged adapters at the round boundary, BEFORE
+                # any sync this round consumes them (main thread only)
+                self.store.drain_prefetched()
+            scale_actions = self._run_control(now)
         enqueued: List[Request] = []
-        while self._pi < len(self._pending) and \
-                self._pending[self._pi].arrival <= now:
-            r = self._pending[self._pi]
-            self._pi += 1
-            if not r.cancelled:             # cancelled while still pending
-                self.sched.enqueue(r, now)
-                if self.store is not None:
-                    # start the REAL staging (disk read + CPU fusion) at
-                    # arrival, overlapped with this round's decode; the
-                    # cache's prefetch_hint (inside enqueue) starts the
-                    # virtual-time load clock in parallel
-                    self.store.prefetch(r.adapter_id)
-                    if self.tracer.enabled:
-                        self.tracer.instant(
-                            "store", f"prefetch a{r.adapter_id}", now,
-                            rid=r.rid, adapter_id=r.adapter_id)
-                if self._scaler is not None:
-                    self._scaler.observe_arrival(now, r.adapter_id)
-                enqueued.append(r)
+        with tr.scope("serve.enqueue"):
+            if traced:
+                wall["enqueue"] = wall_time()
+            while self._pi < len(self._pending) and \
+                    self._pending[self._pi].arrival <= now:
+                r = self._pending[self._pi]
+                self._pi += 1
+                if not r.cancelled:         # cancelled while still pending
+                    self.sched.enqueue(r, now)
+                    if self.store is not None:
+                        # start the REAL staging (disk read + CPU fusion) at
+                        # arrival, overlapped with this round's decode; the
+                        # cache's prefetch_hint (inside enqueue) starts the
+                        # virtual-time load clock in parallel
+                        self.store.prefetch(r.adapter_id)
+                        if traced:
+                            tr.instant("store", f"prefetch a{r.adapter_id}",
+                                       wall_time(), rid=r.rid,
+                                       adapter_id=r.adapter_id)
+                    if self._scaler is not None:
+                        self._scaler.observe_arrival(now, r.adapter_id)
+                    enqueued.append(r)
         # admission at the step boundary, least-loaded instance first
         admitted_all: List[Request] = []
-        for iid in sorted(self.engines,
-                          key=lambda i: (self._instances[i].batch, i)):
-            admitted = self.sched.admit(iid, now)
-            if admitted and ccfg.disaggregated:
-                self._sync_pool()
-            for r in admitted:
-                self.engines[iid].add_request(r.rid, self._prompt(r),
-                                              r.adapter_id)
-                if self.tracer.enabled and self.ccfg.paged:
-                    self.tracer.instant(
-                        "kv", f"kv.alloc r{r.rid}", now, rid=r.rid,
-                        iid=iid,
-                        pages=self._need_by_rid.get(r.rid))
-            admitted_all.extend(admitted)
+        with tr.scope("serve.admit"):
+            for iid in sorted(self.engines,
+                              key=lambda i: (self._instances[i].batch, i)):
+                admitted = self.sched.admit(iid, now)
+                if admitted and ccfg.disaggregated:
+                    with tr.scope("serve.residency_sync"):
+                        self._sync_pool()
+                for r in admitted:
+                    if traced:
+                        wall["admit"][r.rid] = wall_time()
+                    self.engines[iid].add_request(r.rid, self._prompt(r),
+                                                  r.adapter_id)
+                    if traced and self.ccfg.paged:
+                        tr.instant("kv", f"kv.alloc r{r.rid}", wall_time(),
+                                   rid=r.rid, iid=iid,
+                                   pages=self._need_by_rid.get(r.rid))
+                admitted_all.extend(admitted)
+            if admitted_all and self.transport is not None:
+                # install the new residency on the device now, not inside
+                # the first decode step that needs it
+                with tr.scope("serve.residency_sync"):
+                    self.transport.refresh()
         # one decode step per busy instance; requests admitted above are
         # already in the running batch (continuous batching)
         step_end = (self.rnd + 1) * ccfg.step_time
@@ -605,38 +639,38 @@ class Cluster:
         finished: List[Request] = []
         for iid in sorted(self.engines):
             eng = self.engines[iid]
-            if not eng.active_rids():
+            active = eng.active_rids()
+            if not active:
                 continue
             busy = True
-            traced = self.tracer.enabled
-            if traced:
-                batch = len(eng.active_rids())
-                w0 = wall_time()
-            for rid, tok in eng.step().items():
-                self.tokens[rid].append(tok)
-                round_tokens[rid] = tok
-            if traced:
-                # span edges are the VIRTUAL round window; the measured
-                # engine wall time rides along as an attribute
-                self.tracer.span(
-                    f"inst:{iid}", "decode.step", now, step_end,
-                    batch=batch, wall_ms=(wall_time() - w0) * 1e3)
-            for r in self.sched.step_complete(iid, step_end):
-                eng.evict_request(r.rid)
-                finished.append(r)
-                if self._scaler is not None:
-                    self._scaler.observe_finish(step_end,
-                                                r.finish - r.arrival)
-        self._retire_drained()
+            with tr.scope("serve.engine.step", iid=iid, rows=len(active)) \
+                    if traced else NO_SCOPE:
+                for rid, tok in eng.step().items():
+                    self.tokens[rid].append(tok)
+                    round_tokens[rid] = tok
+            with tr.scope("serve.complete"):
+                for r in self.sched.step_complete(iid, step_end):
+                    eng.evict_request(r.rid)
+                    finished.append(r)
+                    if self._scaler is not None:
+                        self._scaler.observe_finish(step_end,
+                                                    r.finish - r.arrival)
+        if traced:
+            wall["end"] = wall_time()
+        with tr.scope("serve.complete"):
+            self._retire_drained()
         self.rnd += 1
-        if self.tracer.enabled:
-            self.tracer.counter("sched", "queue_depth", step_end,
-                                float(self.sched.queue_len()))
+        if traced:
+            tr.counter("sched", "queue_depth", wall_time(),
+                       float(self.sched.queue_len()))
         idle = (not busy and self._pi >= len(self._pending)
                 and self.sched.queue_len() == 0)
-        return {"now": now, "step_end": step_end, "enqueued": enqueued,
-                "admitted": admitted_all, "tokens": round_tokens,
-                "finished": finished, "scale": scale_actions, "idle": idle}
+        rep = {"now": now, "step_end": step_end, "enqueued": enqueued,
+               "admitted": admitted_all, "tokens": round_tokens,
+               "finished": finished, "scale": scale_actions, "idle": idle}
+        if traced:
+            rep["wall"] = wall
+        return rep
 
     def idle(self) -> bool:
         """No running work, no queued work, no pending arrivals."""

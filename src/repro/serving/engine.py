@@ -61,6 +61,8 @@ from repro.core import disagg as disagg_mod
 from repro.core.adapter import AdapterPool
 from repro.models import cache as cache_mod
 from repro.models import transformer
+from repro.obs.clock import wall_time
+from repro.obs.trace import NO_SCOPE, NULL_TRACER
 from repro.transport.base import kv_donating_jit as _kv_jit, make_transport
 
 SLOT_FAMILIES = ("dense", "moe", "vlm")
@@ -203,7 +205,8 @@ class SlotState:
 class Engine:
     def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig,
                  pool: Optional[AdapterPool] = None,
-                 server=None, transport="host", mesh_ctx=None):
+                 server=None, transport="host", mesh_ctx=None,
+                 tracer=None):
         # ``server`` is anything satisfying LoRAServer's ``compute``
         # contract: a single LoRAServer or an elastic ``ServerPool`` of
         # replicas (serving/server_pool.py). The engine never dispatches
@@ -215,7 +218,10 @@ class Engine:
         # ``ExpertParallelCtx``) runs the disaggregated step's base expert
         # GEMMs expert-parallel over its mesh; the KV slab/pool is then
         # committed to the mesh so the step never mixes device assignments.
+        # ``tracer`` (repro.obs) takes the step's and the prefill's
+        # serve.* scopes and the decode bucket counter.
         self.cfg = cfg
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.params = params
         self.ecfg = ecfg
         self.pool = pool
@@ -380,27 +386,40 @@ class Engine:
             for j in range(need):
                 self._bt[slot, j] = self._alloc_page()
         if plen > 1:
-            self._prefill_slot(slot, prompt[:-1])
+            plan = self._chunk_plan(plen - 1)
+            tr = self.tracer
+            with tr.scope("serve.prefill", rid=rid, chunks=len(plan),
+                          tokens=plen - 1,
+                          padded_tokens=sum(w - m for _, w, m in plan)) \
+                    if tr.enabled else NO_SCOPE:
+                self._prefill_slot(slot, prompt[:-1], plan)
         self.slots[slot] = SlotState(rid=rid, adapter_id=int(adapter_id),
                                      pos=plen - 1,
                                      last_token=int(prompt[-1]))
         self._by_rid[rid] = slot
         return slot
 
-    def _prefill_slot(self, slot: int, toks: np.ndarray) -> None:
+    def _chunk_plan(self, n_tok: int):
+        """[(start, width, real tokens)] of the prefill chunks over
+        ``n_tok`` prompt tokens: fixed-width chunks, the last one padded
+        (width minus real tokens is its padding)."""
+        plan = []
+        for c in range(0, n_tok, self._chunk):
+            w = min(self._chunk, self.ecfg.max_len - c)  # writes in the slot
+            plan.append((c, w, min(w, n_tok - c)))
+        return plan
+
+    def _prefill_slot(self, slot: int, toks: np.ndarray, plan) -> None:
         """Chunked prefill: run ``toks`` through fixed-width parallel
-        chunks, each attending over the already-cached context, writing
-        each chunk's KV into the slot's rows (dense) or pages (paged).
-        The final chunk is zero-padded to its width; the padded positions'
-        KV is garbage but sits beyond the slot position, so it is masked by
-        every attention until decode overwrites it."""
-        n_tok = int(toks.shape[0])
-        C = self._chunk
+        chunks (``plan``, from ``_chunk_plan``), each attending over the
+        already-cached context, writing each chunk's KV into the slot's
+        rows (dense) or pages (paged). The final chunk is zero-padded to
+        its width; the padded positions' KV is garbage but sits beyond
+        the slot position, so it is masked by every attention until
+        decode overwrites it."""
         ps = self.ecfg.page_size
-        for c in range(0, n_tok, C):
-            w = min(C, self.ecfg.max_len - c)   # keep writes in the slot
+        for c, w, m in plan:
             chunk = np.zeros((1, w), np.int32)
-            m = min(w, n_tok - c)
             chunk[0, :m] = toks[c:c + m]
             if self.ecfg.paged:
                 pages = jnp.asarray(self._bt[slot, : c // ps])
@@ -408,8 +427,9 @@ class Engine:
             else:
                 k_ctx, v_ctx = _gather_ctx_rows(self._k, self._v,
                                                 jnp.int32(slot), c)
-            k_c, v_c = _prefill_chunk(self.params, self.cfg,
-                                      jnp.asarray(chunk), k_ctx, v_ctx)
+            chunk_j = jnp.asarray(chunk)
+            k_c, v_c = _prefill_chunk(self.params, self.cfg, chunk_j, k_ctx,
+                                      v_ctx)
             if self.ecfg.paged:
                 # w <= max_len - c keeps this slice fully in the block
                 # table; unallocated tail pages (padded final chunk) map to
@@ -462,6 +482,49 @@ class Engine:
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return {}
+        tr = self.tracer
+        with tr.scope("serve.engine.prepare"):
+            nb, args = self._prepare(occupied)
+        if tr.enabled:
+            tr.counter("engine", "decode_bucket", wall_time(), nb)
+        sel_j, sc_j, toks_j, pos_j, ads_j, bt_j = args
+
+        if self.server is not None:
+            tok, self._k, self._v = self.transport.decode_step(
+                self.params, self.cfg, self._k, self._v, toks_j, pos_j,
+                ads_j, self.pool.scale if self.pool else 1.0,
+                sel=sel_j, scatter_idx=sc_j, block_table=bt_j)
+        else:
+            lora_ctx = None
+            if self.pool is not None:
+                lora_ctx = self.pool.lora_ctx(ads_j)
+            with tr.scope("serve.engine.dispatch"):
+                if self.ecfg.paged:
+                    tok, self._k, self._v = _coupled_paged_step(
+                        self.params, self.cfg, self._k, self._v, bt_j,
+                        toks_j, pos_j, lora_ctx)
+                else:
+                    tok, self._k, self._v = _coupled_slot_step(
+                        self.params, self.cfg, self._k, self._v, sel_j,
+                        sc_j, toks_j, pos_j, lora_ctx)
+
+        with tr.scope("serve.engine.sync"):
+            tok = np.asarray(tok)
+        with tr.scope("serve.engine.emit"):
+            out: Dict[int, int] = {}
+            for row, i in enumerate(occupied):
+                s = self.slots[i]
+                t = int(tok[row])
+                s.pos += 1
+                s.last_token = t
+                out[s.rid] = t
+        return out
+
+    def _prepare(self, occupied: List[int]):
+        """The step's host side: the power-of-two bucket, each row's page
+        allocated on demand (paged), and the batch's arrays uploaded.
+        Returns (bucket, (sel, scatter_idx, toks, pos, adapter ids,
+        block table or None) on the device)."""
         nb = _bucket(len(occupied), self.n_slots)
         sel = np.zeros(nb, np.int32)
         sel[: len(occupied)] = occupied
@@ -491,38 +554,10 @@ class Engine:
             toks[row, 0] = s.last_token
             pos_vec[row] = s.pos
             ads[row] = s.adapter_id
-        sel_j = jnp.asarray(sel)
-        sc_j = jnp.asarray(scatter_idx)
-        toks_j, pos_j = jnp.asarray(toks), jnp.asarray(pos_vec)
         bt_j = jnp.asarray(self._bt[sel]) if self.ecfg.paged else None
-
-        if self.server is not None:
-            tok, self._k, self._v = self.transport.decode_step(
-                self.params, self.cfg, self._k, self._v, toks_j, pos_j,
-                jnp.asarray(ads), self.pool.scale if self.pool else 1.0,
-                sel=sel_j, scatter_idx=sc_j, block_table=bt_j)
-        else:
-            lora_ctx = None
-            if self.pool is not None:
-                lora_ctx = self.pool.lora_ctx(jnp.asarray(ads))
-            if self.ecfg.paged:
-                tok, self._k, self._v = _coupled_paged_step(
-                    self.params, self.cfg, self._k, self._v, bt_j, toks_j,
-                    pos_j, lora_ctx)
-            else:
-                tok, self._k, self._v = _coupled_slot_step(
-                    self.params, self.cfg, self._k, self._v, sel_j, sc_j,
-                    toks_j, pos_j, lora_ctx)
-
-        tok = np.asarray(tok)
-        out: Dict[int, int] = {}
-        for row, i in enumerate(occupied):
-            s = self.slots[i]
-            t = int(tok[row])
-            s.pos += 1
-            s.last_token = t
-            out[s.rid] = t
-        return out
+        return nb, (jnp.asarray(sel), jnp.asarray(scatter_idx),
+                    jnp.asarray(toks), jnp.asarray(pos_vec),
+                    jnp.asarray(ads), bt_j)
 
     # ------------------------------------------------------------------ #
     # legacy static-batch API (quickstart / launch.serve / test_system)    #

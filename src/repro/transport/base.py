@@ -152,23 +152,29 @@ class Transport(Protocol):
 
     stats: TransportStats
 
+    def refresh(self) -> bool:
+        """Install residency changes on the device (True on upload)."""
+
     def decode_step(self, params, cfg, k, v, toks, pos_vec, adapter_ids,
                     lora_scale, *, sel=None, scatter_idx=None,
-                    block_table=None): ...
+                    block_table=None):
+        """(token ids on the device, k, v): the caller pulls the ids to
+        the host, which is where it waits for the step."""
 
 
 def make_transport(name: str, server, n_adapters: Optional[int] = None,
-                   mesh_ctx=None) -> Transport:
+                   mesh_ctx=None, tracer=None) -> Transport:
     """Build the named transport plane over ``server`` (a ``ServerPool``
     or a legacy single ``LoRAServer``). ``mesh_ctx`` (an
     ``ExpertParallelCtx``) runs the base expert GEMMs of either plane
-    expert-parallel over its mesh."""
+    expert-parallel over its mesh; ``tracer`` (a ``repro.obs`` tracer)
+    takes the step's ``serve.*`` scopes."""
     from repro.transport.fused import FusedTransport
     from repro.transport.host import HostTransport
     if name == "host":
-        return HostTransport(server, mesh_ctx=mesh_ctx)
+        return HostTransport(server, mesh_ctx=mesh_ctx, tracer=tracer)
     if name == "fused":
         return FusedTransport(server, n_adapters=n_adapters,
-                              mesh_ctx=mesh_ctx)
+                              mesh_ctx=mesh_ctx, tracer=tracer)
     raise ValueError(f"unknown transport {name!r} "
                      f"(expected 'host' or 'fused')")

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import disagg as disagg_mod
+from repro.obs.trace import NULL_TRACER
 from repro.transport.base import TransportStats, kv_donating_jit
 
 F32 = jnp.float32
@@ -100,7 +101,8 @@ def _fused_dense_fn(params, cfg, k, v, sel, scatter_idx, toks, pos_vec,
     logits, k_rows, v_rows = disagg_mod.disagg_decode_step_slots(
         params, cfg, k_rows, v_rows, toks, pos_vec, view, ads, scale,
         mesh_ctx=mesh_ctx)
-    tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+    with jax.named_scope("lm_head"):
+        tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
     k = k.at[:, scatter_idx].set(k_rows, mode="drop")
     v = v.at[:, scatter_idx].set(v_rows, mode="drop")
     return tok, k, v
@@ -115,7 +117,8 @@ def _fused_paged_fn(params, cfg, k_pool, v_pool, bt, toks, pos_vec, view,
     logits, k_pool, v_pool = disagg_mod.disagg_decode_step_slots(
         params, cfg, k_pool, v_pool, toks, pos_vec, view, ads, scale,
         block_table=bt, mesh_ctx=mesh_ctx)
-    tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+    with jax.named_scope("lm_head"):
+        tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
     return tok, k_pool, v_pool
 
 
@@ -160,10 +163,11 @@ class FusedTransport:
     name = "fused"
 
     def __init__(self, server, n_adapters: Optional[int] = None,
-                 mesh_ctx=None):
+                 mesh_ctx=None, tracer=None):
         self.server = server
         self.n_adapters = n_adapters
         self.mesh_ctx = mesh_ctx
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = TransportStats(transport="fused")
         self._view: Optional[DeviceLoraView] = None
         self._fingerprint = None
@@ -244,21 +248,20 @@ class FusedTransport:
     def decode_step(self, params, cfg, k, v, toks, pos_vec, adapter_ids,
                     lora_scale, *, sel=None, scatter_idx=None,
                     block_table=None):
-        self.refresh()
+        tr = self.tracer
+        with tr.scope("serve.transport.refresh"):
+            self.refresh()
         st = self.stats
         st.steps += 1
         st.host_dispatches += 1          # the ONE fused program launch
         st.observe_ranks(self.server, adapter_ids)
         scale = jnp.asarray(lora_scale, F32)
-        if block_table is not None:
-            tok, k, v = self._paged(params, cfg, k, v, block_table, toks,
-                                    pos_vec, self._view, adapter_ids,
-                                    scale)
-        else:
-            tok, k, v = self._dense(params, cfg, k, v, sel, scatter_idx,
-                                    toks, pos_vec, self._view, adapter_ids,
-                                    scale)
-        return np.asarray(tok), k, v
+        with tr.scope("serve.engine.dispatch"):
+            if block_table is not None:
+                return self._paged(params, cfg, k, v, block_table, toks,
+                                   pos_vec, self._view, adapter_ids, scale)
+            return self._dense(params, cfg, k, v, sel, scatter_idx, toks,
+                               pos_vec, self._view, adapter_ids, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("hook", "layer"))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import disagg as disagg_mod
+from repro.obs.trace import NULL_TRACER
 from repro.transport.base import TransportStats, gather_rows, scatter_rows
 
 
@@ -52,11 +53,15 @@ class HostTransport:
 
     name = "host"
 
-    def __init__(self, server, mesh_ctx=None):
+    def __init__(self, server, mesh_ctx=None, tracer=None):
         self.server = server
         self.mesh_ctx = mesh_ctx
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = TransportStats(transport="host")
         self._counting = _CountingServer(server, self.stats)
+
+    def refresh(self) -> bool:
+        return False            # residency lives on the host: no upload
 
     def decode_step(self, params, cfg, k, v, toks, pos_vec, adapter_ids,
                     lora_scale, *, sel=None, scatter_idx=None,
@@ -64,6 +69,15 @@ class HostTransport:
         st = self.stats
         st.steps += 1
         st.observe_ranks(self.server, adapter_ids)
+        # the eager step IS the dispatch: every hook returns to the host
+        with self.tracer.scope("serve.engine.dispatch"):
+            return self._step(params, cfg, k, v, toks, pos_vec,
+                              adapter_ids, lora_scale, sel, scatter_idx,
+                              block_table)
+
+    def _step(self, params, cfg, k, v, toks, pos_vec, adapter_ids,
+              lora_scale, sel, scatter_idx, block_table):
+        st = self.stats
         if block_table is not None:
             logits, k, v = disagg_mod.disagg_decode_step_slots(
                 params, cfg, k, v, toks, pos_vec, self._counting,
@@ -79,4 +93,4 @@ class HostTransport:
             st.host_dispatches += 3          # gather + scatter + select
         logits = logits[:, : cfg.vocab_size]
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return np.asarray(tok), k, v
+        return tok, k, v

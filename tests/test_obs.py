@@ -10,19 +10,26 @@ Pins the PR-10 contracts:
   - trace=True covers each request's full TTFT window (>= 95%: queue
     wait + staging/prefill attribution)
   - NullTracer is the zero-cost default: enabled=False and the no-op
-    fast path allocates nothing
+    fast path (scope included) allocates nothing
   - exporters match golden files (tests/golden/obs_*)
+  - the cluster plane's serve.* scopes are on the wall clock, nest, and
+    hold one serve.engine.step per busy instance a round; with tracing
+    off no scope arguments are built; the compiled decode step names its
+    regions (lora_hook / moe_experts / attention)
 """
 import dataclasses
 import json
 import pathlib
+import re
 import tracemalloc
 
 import pytest
 
 from repro.configs import get_config
 from repro.obs import (NULL_TRACER, MetricsRegistry, NullTracer,
-                       TimelineTracer, to_jsonl, to_perfetto, to_prometheus)
+                       TimelineTracer, to_jsonl, to_perfetto, to_prometheus,
+                       wall_time)
+from repro.obs.trace import NO_SCOPE
 from repro.serving.api import ServeConfig, build_system
 
 GOLDEN = pathlib.Path(__file__).resolve().parent / "golden"
@@ -60,6 +67,59 @@ def test_null_tracer_fast_path_allocates_nothing():
             if s.size_diff > 0
             and s.traceback[0].filename == trace_mod.__file__]
     assert not grew, grew
+
+
+def test_null_tracer_scope_allocates_nothing():
+    tr = NULL_TRACER
+    assert tr.scope("serve.a") is tr.scope("serve.b", rows=2)
+    with tr.scope("serve.a"):               # warm up before measuring
+        pass
+    tracemalloc.start()
+    snap1 = tracemalloc.take_snapshot()
+    for _ in range(1000):
+        with tr.scope("serve.round"):
+            with tr.scope("serve.engine.step"):
+                pass
+    snap2 = tracemalloc.take_snapshot()
+    tracemalloc.stop()
+    import repro.obs.trace as trace_mod
+    grew = [s for s in snap2.compare_to(snap1, "lineno")
+            if s.size_diff > 0
+            and s.traceback[0].filename == trace_mod.__file__]
+    assert not grew, grew
+
+
+def test_timeline_tracer_scopes_nest_on_the_wall_clock():
+    notes = []
+
+    class Note:
+        def __init__(self, name, **kw):
+            notes.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    tr = TimelineTracer(annotate=Note)
+    t0 = wall_time()
+    with tr.scope("serve.round", round=0) as outer:
+        with tr.scope("serve.prefill", rid=7):
+            pass
+        with tr.scope("serve.engine.step"):
+            pass
+    t1 = wall_time()
+    inner = [s for s in tr.spans if s.parent is outer]
+    assert [s.name for s in inner] == ["serve.prefill", "serve.engine.step"]
+    assert outer.parent is None and outer.args == {"round": 0}
+    assert t0 <= outer.start <= inner[0].start <= inner[0].end \
+        <= inner[1].start <= inner[1].end <= outer.end <= t1
+    assert tr.children(outer) == inner
+    # every scope entered the injected profiler annotation, args included
+    assert notes == [("serve.round", {"round": 0}),
+                     ("serve.prefill", {"rid": 7}),
+                     ("serve.engine.step", {})]
 
 
 def test_timeline_tracer_records_and_finishes_open_spans():
@@ -240,13 +300,17 @@ def cluster_setup():
 SPECS = [(0, 0.0, 5, 6), (1, 0.0, 4, 4), (2, 2.0, 6, 5)]
 
 
-def _cluster_run(setup, trace, paged=False, transport="host"):
+def _cluster_system(setup, trace, paged=False, transport="host"):
     cfg, params, pool = setup
     sc = ServeConfig(backend="cluster", disaggregated=True, n_instances=1,
                      max_batch=2, max_len=32, adapter_cache_slots=4,
                      paged=paged, page_size=4, n_pages=8, prefill_chunk=8,
                      transport=transport, trace=trace)
-    system = build_system(sc, cfg, params=params, pool=pool)
+    return build_system(sc, cfg, params=params, pool=pool)
+
+
+def _cluster_run(setup, trace, paged=False, transport="host"):
+    system = _cluster_system(setup, trace, paged, transport)
     handles = [system.submit(adapter_id=a, arrival=t, prompt_len=p,
                              max_new_tokens=o) for a, t, p, o in SPECS]
     system.drain()
@@ -269,27 +333,134 @@ def test_cluster_tracing_on_off_tokens_bit_identical(cluster_setup, paged,
         kv = [i for i in obs.tracer.instants if i.track == "kv"]
         assert len(kv) == len(SPECS)            # one alloc per admission
         assert all(i.args["pages"] >= 1 for i in kv)
-    steps = [s for s in obs.tracer.spans if s.name == "decode.step"]
-    assert steps and all(s.args["wall_ms"] >= 0.0 for s in steps)
+    steps = [s for s in obs.tracer.spans if s.name == "serve.engine.step"]
+    assert steps and all(s.args["rows"] >= 1 and s.duration >= 0.0
+                         for s in steps)
 
 
 def test_cluster_trace_covers_full_ttft_window(cluster_setup):
-    """Acceptance: queue + staging + prefill spans cover >= 95% of each
-    request's TTFT (here exactly 100%: stage spans are contiguous from
-    the queued event to the first token)."""
-    system, _ = _cluster_run(cluster_setup, True)
+    """Acceptance: the queued + prefill stage spans cover >= 95% of each
+    request's TTFT window, and the ttft_seconds histogram agrees with it.
+    The window is timed apart from the spans, on the caller's reading of
+    the same wall clock: from the start of the round that enqueued the
+    request to the moment its first token reached the handle."""
+    system = _cluster_system(cluster_setup, True)
+    first_token, round_start = {}, {}
+
+    def on_token(h, tok):
+        first_token.setdefault(h.rid, wall_time())
+
+    handles = [system.submit(adapter_id=a, arrival=t, prompt_len=p,
+                             max_new_tokens=o, on_token=on_token)
+               for a, t, p, o in SPECS]
+    while not system.backend.idle():
+        t = wall_time()
+        for ev in system.step():
+            if ev.kind == "queued":
+                round_start[ev.rid] = t
+    assert all(h.state.name == "FINISHED" for h in handles)
     obs = system.observability()
     trace = obs.perfetto()
     assert trace["traceEvents"]
     tr = obs.tracer
-    for h in system.handles.values():
+    windows = []
+    for h in handles:
         spans = {s.name: s for s in tr.spans_for(f"req:{h.rid}")}
-        ttft = spans["prefill"].end - spans["queued"].start
+        window = first_token[h.rid] - round_start[h.rid]
         covered = spans["queued"].duration + spans["prefill"].duration
-        assert ttft > 0 and covered / ttft >= 0.95
-        # ... and the request-level TTFT metric agrees with the span view
-        assert ttft == pytest.approx(
-            h.request.first_token - h.request.arrival)
+        assert round_start[h.rid] <= spans["queued"].start
+        assert spans["prefill"].end <= first_token[h.rid]
+        assert 0.95 * window <= covered <= window, (h.rid, covered, window)
+        windows.append(window)
+    # ... and the request-level TTFT metric agrees with the windows
+    hist = obs.registry.get("ttft_seconds")
+    assert hist.count == len(windows)
+    assert 0.95 * sum(windows) <= hist.sum <= sum(windows)
+
+
+def test_cluster_serve_scopes_nest_once_per_busy_instance(cluster_setup):
+    """serve.* scopes are on the wall clock, nest inside their round, and
+    every serve.round holds one serve.engine.step per busy instance."""
+    cfg, params, pool = cluster_setup
+    sc = ServeConfig(backend="cluster", disaggregated=True, n_instances=2,
+                     max_batch=2, max_len=32, adapter_cache_slots=4,
+                     paged=True, page_size=4, n_pages=8, prefill_chunk=8,
+                     transport="fused", trace=True)
+    system = build_system(sc, cfg, params=params, pool=pool)
+    for a, t, p, o in SPECS + [(3, 0.0, 7, 3)]:
+        system.submit(adapter_id=a, arrival=t, prompt_len=p,
+                      max_new_tokens=o)
+    t0 = wall_time()
+    tokens_per_round = []
+    while not system.backend.idle():
+        evs = system.step()
+        tokens_per_round.append(sum(e.kind == "token" for e in evs))
+    t1 = wall_time()
+    tr = system.observability().tracer
+    serve = [s for s in tr.spans if s.track == "serve"
+             and s.name != "serve.gc"]
+    assert serve and all(t0 <= s.start <= s.end <= t1 for s in serve)
+    for s in serve:                                   # proper nesting
+        if s.parent is not None:
+            assert s.parent.start <= s.start <= s.end <= s.parent.end
+    rounds = sorted((s for s in serve if s.name == "serve.round"),
+                    key=lambda s: s.start)
+    assert len(rounds) == len(tokens_per_round)
+    for a, b in zip(rounds, rounds[1:]):              # monotone
+        assert a.end <= b.start
+    for rnd, n_tok in zip(rounds, tokens_per_round):
+        steps = [c for c in tr.children(rnd)
+                 if c.name == "serve.engine.step"]
+        assert len({c.args["iid"] for c in steps}) == len(steps)
+        assert sum(c.args["rows"] for c in steps) == n_tok
+        for st in steps:                  # a collection may land anywhere
+            assert [c.name for c in tr.children(st)
+                    if c.name != "serve.gc"] == [
+                "serve.engine.prepare", "serve.transport.refresh",
+                "serve.engine.dispatch", "serve.engine.sync",
+                "serve.engine.emit"]
+    assert any(s.name == "serve.events" and s.parent is None
+               for s in serve)
+    prefills = [s for s in serve if s.name == "serve.prefill"]
+    assert len(prefills) == len(SPECS) + 1
+    assert all(s.parent.name == "serve.admit" and
+               s.args["tokens"] + s.args["padded_tokens"]
+               == 8 * s.args["chunks"] for s in prefills)
+    system.close()
+
+
+def test_tracing_off_builds_no_scope_arguments(cluster_setup,
+                                               monkeypatch):
+    """With tracing off, no producer builds a scope's keyword arguments:
+    the scopes that take some are skipped for the shared no-op context."""
+    from repro.obs.trace import NullTracer
+    calls = []
+
+    def scope(self, name, **args):
+        calls.append((name, args))
+        return NO_SCOPE
+
+    monkeypatch.setattr(NullTracer, "scope", scope)
+    _cluster_run(cluster_setup, False, True, "fused")
+    names = {name for name, _ in calls}
+    assert {"serve.engine.prepare", "serve.engine.dispatch",
+            "serve.events"} <= names
+    assert not {"serve.round", "serve.engine.step",
+                "serve.prefill"} & names
+    assert all(args == {} for _, args in calls)
+
+
+def test_garbage_collections_become_spans_until_close():
+    import gc
+    tr = TimelineTracer(gc_spans=True)
+    gc.collect()
+    spans = [s for s in tr.spans if s.name == "serve.gc"]
+    assert spans and spans[-1].args["generation"] == 2
+    assert spans[-1].args["collected"] >= 0
+    tr.close()
+    n = len(tr.spans)
+    gc.collect()
+    assert len(tr.spans) == n
 
 
 def test_cluster_prometheus_and_perfetto_exports(cluster_setup):
@@ -303,7 +474,7 @@ def test_cluster_prometheus_and_perfetto_exports(cluster_setup):
         assert name in text, name
     trace = obs.perfetto()
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"queued", "prefill", "decode", "decode.step",
+    assert {"queued", "prefill", "decode", "serve.engine.step",
             "queue_depth"} <= names
     phases = {e["ph"] for e in trace["traceEvents"]}
     assert {"X", "M", "C"} <= phases
@@ -311,3 +482,68 @@ def test_cluster_prometheus_and_perfetto_exports(cluster_setup):
     tids = {e["tid"] for e in trace["traceEvents"] if e["ph"] == "M"}
     assert all(e["tid"] in tids for e in trace["traceEvents"]
                if e["ph"] != "M")
+
+
+# ------------------ named scopes of a described v5e compile -------------- #
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described ``v5e:2x2`` topology (as in
+    tests/test_tpu_compile.py): compiled for, never run on."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler to describe it with
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+def test_fused_decode_scope_map_on_described_v5e(one_chip):
+    import jax
+    import jax.numpy as jnp
+    from repro.models import cache as cache_mod
+    from repro.models.model import abstract_params
+    from repro.transport import fused
+    cfg = dataclasses.replace(get_config("qwen3-moe-235b-a22b").reduced(),
+                              lora_targets=("gate", "up", "down"),
+                              lora_rank=4)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype),
+                                    abstract_params(cfg))
+    L, E, d, ff = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff
+    r, M, B, ps, n_pages = 4, 4, 2, 4, 8
+    view = fused.DeviceLoraView(
+        s((1, L, M, E, d, 2 * r)), s((1, L, M, E, 2 * r, 2 * ff)),
+        s((1, L, M, E, ff, r)), s((1, L, M, E, r, d)),
+        s((8,), jnp.int32), s((1, M), jnp.int32))
+    kv = jax.eval_shape(lambda: cache_mod.init_paged_cache(cfg, n_pages, ps))
+    pool = s(kv["k"].shape, kv["k"].dtype)
+    step = jax.jit(fused._fused_paged_fn, static_argnames=("cfg",))
+    text = step.lower(params, cfg, pool, pool, s((B, n_pages // B),
+                                                 jnp.int32),
+                      s((B, 1), jnp.int32), s((B,), jnp.int32), view,
+                      s((B,), jnp.int32), s((), jnp.float32)
+                      ).compile().as_text()
+    # each compiled instruction carries its scope path in its metadata,
+    # which a profile's HLO keeps for the benchmark's join
+    held = {}
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        for part in op_name.split("/"):
+            held[part] = held.get(part, 0) + 1
+    for scope in ("lora_hook", "moe_experts", "attention"):
+        assert held.get(scope, 0) >= 1, (scope, sorted(held))
