@@ -143,6 +143,50 @@ def _replicate_eager(d, mesh_ctx):
     return jax.device_put(d, NamedSharding(mesh_ctx.mesh, P()))
 
 
+def _capacity(cfg: ModelConfig, T: int) -> int:
+    """Per-expert dispatch slots for ``T`` decode tokens: the same dropless
+    threshold as the coupled path (_moe_local). The two paths must drop (or
+    not drop) identically at EVERY batch size, else the coupled==disagg
+    token equality breaks on huge decode buckets."""
+    K = cfg.top_k
+    return moe_mod.capacity(T, K, cfg.n_experts, cfg.capacity_factor,
+                            dropless=(T * K <= 4096))
+
+
+def hook_rows(cfg: ModelConfig, T: int) -> int:
+    """Rows one hook call computes for ``T`` decode tokens: the most live
+    (token, expert) pairs the ``E x C`` dispatch buffer can hold,
+    ``min(E*C, T*top_k)``. Dropless decode has ``C >= T*top_k``, so this
+    is ``T*top_k``: one row in ``E`` of the buffer."""
+    return min(cfg.n_experts * _capacity(cfg, T), T * cfg.top_k)
+
+
+def _live_pairs(slot_tok, adapter_ids, T: int, P: int):
+    """The live (token, expert) pairs of a dispatch buffer, in slot order,
+    padded to the static length ``P``: each pair's dispatch-slot index
+    (padding: one past the buffer), a clamped copy to gather with, and its
+    adapter id (padding: -1, so its delta is exact 0.0)."""
+    n = slot_tok.shape[0]
+    (pair,) = jnp.nonzero(slot_tok < T, size=P, fill_value=n)
+    pair_slot = jnp.minimum(pair, n - 1)
+    tok = jnp.minimum(slot_tok[pair_slot], T - 1)
+    pair_adapter = jnp.where(pair < n, jnp.asarray(adapter_ids)[tok], -1)
+    return pair, pair_slot, pair_adapter
+
+
+def _hook_delta(server, hook: str, l: int, rows, pairs, C: int,
+                mesh_ctx=None):
+    """The server's delta for dispatch rows (E*C, d_in) -> (E*C, d_out),
+    computed on the live pairs only; every other row is exact 0.0, as the
+    server gives a row of adapter -1."""
+    pair, pair_slot, pair_adapter = pairs
+    d = server.compute(hook, l, rows[pair_slot], pair_adapter,
+                       pair_slot // C)
+    d = _replicate_eager(d, mesh_ctx)
+    return jnp.zeros((rows.shape[0], d.shape[-1]), d.dtype).at[pair].set(
+        d, mode="drop")
+
+
 def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
                      adapter_ids, lora_scale: float, mesh_ctx=None):
     """One MoE layer with the two server hook points (paper Fig. 7b): base
@@ -150,7 +194,9 @@ def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
     combine. x: (B, 1, d) post-attention residual; adapter_ids: (B,) global
     ids (-1 rows get zero delta). Shared by BOTH decode-step variants so the
     hook math cannot diverge between them. With ``mesh_ctx`` the three base
-    expert GEMMs run expert-parallel over the mesh (see ``_ep_einsum``)."""
+    expert GEMMs run expert-parallel over the mesh (see ``_ep_einsum``).
+    The server computes ``hook_rows`` rows per hook, the live pairs, not
+    the ``E x C`` dispatch buffer."""
     B = x.shape[0]
     E, K = cfg.n_experts, cfg.top_k
     with jax.named_scope("moe_router"):
@@ -158,18 +204,10 @@ def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
         xf = h.reshape(-1, cfg.d_model)
         T = xf.shape[0]
         ids, wts = moe_mod.route(xf, lp["moe"]["router"], E, K)
-        # same dropless threshold as the coupled path (_moe_local): the
-        # two paths must drop (or not drop) identically at EVERY batch
-        # size, else the coupled==disagg token equality breaks on huge
-        # decode buckets
-        C = moe_mod.capacity(T, K, E, cfg.capacity_factor,
-                             dropless=(T * K <= 4096))
+        C = _capacity(cfg, T)
         xe, slot_tok = moe_mod.local_dispatch(xf, ids, C, E)  # (E, C, d)
-        rows = xe.reshape(E * C, cfg.d_model)
-        row_expert = (jnp.arange(E * C, dtype=jnp.int32) // C)
         tok_safe = jnp.minimum(slot_tok, T - 1)
-        row_adapter = jnp.where(slot_tok < T,
-                                jnp.asarray(adapter_ids)[tok_safe], -1)
+        pairs = _live_pairs(slot_tok, adapter_ids, T, hook_rows(cfg, T))
 
     # hook 1: up/gate — client GEMM + server delta (overlapped on HW)
     mp = lp["moe"]
@@ -177,8 +215,8 @@ def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
         g = _ep_einsum("ecd,edf->ecf", xe, mp["gate"], mesh_ctx)
         u = _ep_einsum("ecd,edf->ecf", xe, mp["up"], mesh_ctx)
     with jax.named_scope("lora_hook"):
-        d_up = server.compute("up", l, rows, row_adapter, row_expert)
-        d_up = _replicate_eager(d_up, mesh_ctx)
+        d_up = _hook_delta(server, "up", l, xe.reshape(E * C, -1), pairs, C,
+                           mesh_ctx)
         d_up = d_up.reshape(E, C, -1) * lora_scale
         dg, du = jnp.split(d_up, 2, axis=-1)
     with jax.named_scope("moe_experts"):
@@ -186,9 +224,8 @@ def _moe_hooks_layer(x, lp, cfg: ModelConfig, l: int, server: LoRAServer,
         # hook 2: down
         y = _ep_einsum("ecf,efd->ecd", act, mp["down"], mesh_ctx)
     with jax.named_scope("lora_hook"):
-        d_dn = server.compute("down", l, act.reshape(E * C, -1),
-                              row_adapter, row_expert)
-        d_dn = _replicate_eager(d_dn, mesh_ctx)
+        d_dn = _hook_delta(server, "down", l, act.reshape(E * C, -1), pairs,
+                           C, mesh_ctx)
         y = y + d_dn.reshape(E, C, -1) * lora_scale
 
     # combine with router weights (same bookkeeping as the coupled path)

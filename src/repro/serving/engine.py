@@ -219,7 +219,8 @@ class Engine:
         # GEMMs expert-parallel over its mesh; the KV slab/pool is then
         # committed to the mesh so the step never mixes device assignments.
         # ``tracer`` (repro.obs) takes the step's and the prefill's
-        # serve.* scopes and the decode bucket counter.
+        # serve.* scopes, the decode bucket counter and, on the
+        # disaggregated plane, the rows each LoRA hook call computes.
         self.cfg = cfg
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.params = params
@@ -486,7 +487,11 @@ class Engine:
         with tr.scope("serve.engine.prepare"):
             nb, args = self._prepare(occupied)
         if tr.enabled:
-            tr.counter("engine", "decode_bucket", wall_time(), nb)
+            now = wall_time()
+            tr.counter("engine", "decode_bucket", now, nb)
+            if self.server is not None:
+                tr.counter("engine", "hook_rows", now,
+                           disagg_mod.hook_rows(self.cfg, nb))
         sel_j, sc_j, toks_j, pos_j, ads_j, bt_j = args
 
         if self.server is not None:

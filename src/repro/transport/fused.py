@@ -41,16 +41,35 @@ from repro.transport.base import TransportStats, kv_donating_jit
 F32 = jnp.float32
 
 
+# The TPU compiler splits a gather whose slices are larger than this, and
+# does so by first copying the whole operand into column blocks: for a slot
+# pool, a copy of the pool on every step (Mixtral's 0.94 GB up_B pool).
+_GATHER_SLICE_BYTES = 1 << 19
+
+
+def _pool_blocks(pool, flat):
+    """The (d_in, r) or (r, d_out) blocks of a (R, L, M, E, ...) slot pool
+    at flat indices ``flat`` (T,), read from the pool as it is stored: one
+    gather where a block fits one gather slice, else one dynamic slice per
+    row (either way no copy of the pool)."""
+    blocks = pool.reshape(-1, *pool.shape[4:])
+    if blocks[0].size * blocks.dtype.itemsize <= _GATHER_SLICE_BYTES:
+        return blocks[flat]
+    return jnp.stack([jax.lax.dynamic_index_in_dim(blocks, flat[t], 0, False)
+                      for t in range(flat.shape[0])])
+
+
 @jax.tree_util.register_pytree_node_class
 class DeviceLoraView:
     """Device-resident LoRA routing state: stacked replica slot pools
     (R, L, M, E, d_in, r) per hook factor + the adapter->slot LUT.
 
     ``compute`` is the traced twin of ``LoRAServer.compute``'s flat path:
-    the same gathers and the same f32 einsum contraction per row, with the
-    affinity home ``aid % R`` replacing the host-side replica masking (each
-    row reads exactly the array its home replica holds, and inactive rows
-    are exact 0.0 — bit-compatible with the host plane's masked sum)."""
+    the same per-row blocks (read in place, ``_pool_blocks``) and the same
+    f32 einsum contraction per row, with the affinity home ``aid % R``
+    replacing the host-side replica masking (each row reads exactly the
+    array its home replica holds, and inactive rows are exact 0.0 —
+    bit-compatible with the host plane's masked sum)."""
 
     def __init__(self, up_A, up_B, down_A, down_B, slot_lut, slot_ranks):
         self.up_A, self.up_B = up_A, up_B
@@ -77,8 +96,10 @@ class DeviceLoraView:
         homes = jnp.where(slots >= 0, jnp.maximum(ids, 0) % R, 0)
         ss = jnp.maximum(slots, 0)
         eids = jnp.asarray(expert_ids, jnp.int32)
-        a = A[homes, layer, ss, eids]           # (T, d_in, r)
-        b = B[homes, layer, ss, eids]           # (T, r, d_out)
+        _, L, M, E = A.shape[:4]
+        flat = ((homes * L + layer) * M + ss) * E + eids
+        a = _pool_blocks(A, flat)               # (T, d_in, r)
+        b = _pool_blocks(B, flat)               # (T, r, d_out)
         h = jnp.einsum("td,tdr->tr", rows.astype(F32), a.astype(F32))
         # rank bound: past-rank lanes of h hold exact 0.0 already (the pool
         # zero-pads them), so trimming them is bitwise-neutral. The "up"

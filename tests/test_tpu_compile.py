@@ -1,5 +1,6 @@
 """The served path's Pallas kernels compile for one TPU v5e chip at
-Mixtral-8x7B's published widths.
+Mixtral-8x7B's published widths, and the fused LoRA hook reads its live
+rows' adapter blocks from the slot pool without copying the pool.
 
 The chip is described, not attached: each case lowers a kernel's jitted
 wrapper with Mosaic (``interpret=False``) for one device of a described
@@ -78,3 +79,44 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _case(name, one_chip)
     compiled = fn.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (architecture, live rows of one hook call): Mixtral's blocks are larger
+# than one gather slice (read by dynamic slices), the fine-grained MoE's
+# fit one (gathered)
+HOOK_CASES = [("mixtral-8x7b", BATCH * CFG.top_k), ("qwen3-moe-235b-a22b", 32)]
+
+
+@pytest.mark.parametrize("arch,rows", HOOK_CASES, ids=[a for a, _ in
+                                                        HOOK_CASES])
+@pytest.mark.parametrize("hook", ["up", "down"])
+def test_fused_hook_reads_slot_pool_in_place_on_v5e(arch, rows, hook,
+                                                    one_chip):
+    """The device view's hook, compiled for v5e with 2 layers and 8 slots
+    of pool: its scratch memory holds the rows' blocks, not a copy of the
+    pool (the compiler copies a pool it gathers large slices from into
+    column blocks, on every step)."""
+    from repro.transport.fused import DeviceLoraView, fused_hook_delta
+    cfg = get_config(arch)
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (1, 2, N_ADAPTERS, cfg.n_experts)
+    d, ff, r = cfg.d_model, cfg.d_ff, cfg.lora_rank
+    view = DeviceLoraView(s(*pool, d, 2 * r), s(*pool, 2 * r, 2 * ff),
+                          s(*pool, ff, r), s(*pool, r, d),
+                          s(16, dtype=jnp.int32),
+                          s(1, N_ADAPTERS, dtype=jnp.int32))
+    d_in = d if hook == "up" else ff
+    ids = s(rows, dtype=jnp.int32)
+    compiled = fused_hook_delta.lower(view, hook, 1, s(rows, d_in), ids,
+                                      ids).compile()
+    A, B = (view.up_A, view.up_B) if hook == "up" else \
+        (view.down_A, view.down_B)
+    pool_bytes = (A.size + B.size) * 2
+    n_blocks = 2 * N_ADAPTERS * cfg.n_experts
+    rows_f32_bytes = rows * (A.size + B.size) // n_blocks * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= min(rows_f32_bytes, pool_bytes // 4), (
+        temp, rows_f32_bytes, pool_bytes)

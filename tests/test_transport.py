@@ -9,6 +9,10 @@
     bit-identical across both transports, both KV layouts, 1 and 2 server
     replicas, adapter-cache eviction churn, and an autoscaler-driven
     resize mid-run
+  - the hooks compute only the live (token, expert) pairs of the
+    dispatch buffer: the same deltas bit for bit as over every row, on
+    both the device view and the host server, with ``hook_rows`` rows
+    per hook call and the ``hook_rows`` counter reading it
   - ``transport_stats()`` is exposed through ``ServeSystem`` on both
     execution planes, and the sim plane prices the host launch tail
     (``SimConfig.hook_launch_us``) that the fused plane avoids
@@ -344,3 +348,158 @@ def test_device_view_matches_server_pool_compute(model_cfg):
     want = sp.compute("down", 0, hrows, np.asarray(ads), np.asarray(eids))
     got = fused_hook_delta(tr._view, "down", 0, hrows, ads, eids)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+# ------------------ live-pair hooks (no padded dispatch rows) ------------- #
+def _routing_cfg(E, K, capacity_factor=1.25):
+    return dataclasses.replace(get_config("qwen3-moe-235b-a22b").reduced(),
+                               n_experts=E, top_k=K, d_ff=64,
+                               capacity_factor=capacity_factor,
+                               lora_targets=("gate", "up", "down"),
+                               lora_rank=8)
+
+
+# name: (E, top_k, tokens, capacity or None = decode capacity, adapter
+# ranks, inactive rows, one dynamic slice per block instead of a gather)
+LIVE_PAIR_CASES = {
+    "mixtral_routing": (8, 2, 8, None, [8, 8, 8, 8], 0, False),
+    "qwen_routing": (16, 8, 4, None, [8, 8, 8, 8], 0, False),
+    "inactive_slots": (8, 2, 8, None, [8, 8, 8, 8], 3, False),
+    "mixed_ranks": (16, 8, 4, None, [2, 8, 4, 8], 0, False),
+    "capacity_drops": (8, 2, 32, 4, [2, 8, 4, 8], 2, False),
+    "per_row_slices": (8, 2, 8, None, [2, 8, 4, 8], 1, True),
+}
+
+
+@pytest.mark.parametrize("case", list(LIVE_PAIR_CASES))
+def test_live_pair_hook_deltas_bit_identical_to_all_rows(case, monkeypatch):
+    """The hooks compute only the live (token, expert) pairs and scatter
+    their deltas back: the (E, C, d) deltas equal the all-rows computation
+    over the whole dispatch buffer bit for bit, under the fused plane's
+    device view and the host LoRAServer, for Mixtral- and Qwen-like
+    routing, inactive rows (adapter -1), mixed true ranks and a capacity
+    that drops pairs."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import disagg
+    from repro.core.adapter import init_mixed_rank_pool
+    from repro.core.lora_server import pool_tensors_from_adapter
+    from repro.models import moe as moe_mod
+    from repro.transport import FusedTransport
+    from repro.transport import fused as fused_mod
+    E, K, T, C, ranks, n_off, per_row = LIVE_PAIR_CASES[case]
+    if per_row:     # every block above the gather limit: the slice path
+        monkeypatch.setattr(fused_mod, "_GATHER_SLICE_BYTES", 0)
+    cfg = _routing_cfg(E, K)
+    pool = init_mixed_rank_pool(cfg, ranks, jax.random.PRNGKey(E + K),
+                                dtype=jnp.float32)
+    sp = ServerPool.build(cfg, pool, cache_slots=4)
+    cache = LoRACache(4, adapter_bytes=0.0, n_layers=cfg.n_layers,
+                      layerwise=False, prefetch=False)
+    for aid in range(4):
+        cache.admit(aid, 0.0)
+    sp.sync(cache, tensors_fn=lambda a: pool_tensors_from_adapter(pool, a),
+            rank_fn=lambda a: ranks[a])
+    tr = FusedTransport(sp, n_adapters=4)
+    tr.refresh()
+
+    rng = np.random.default_rng(T * E + K)
+    C = C or disagg._capacity(cfg, T)
+    ids = jnp.asarray(np.argsort(rng.normal(size=(T, E)), 1)[:, :K]
+                      .astype(np.int32))
+    x = jnp.asarray(rng.normal(size=(T, cfg.d_model)).astype(np.float32))
+    xe, slot_tok = moe_mod.local_dispatch(x, ids, C, E)
+    live = int(np.sum(np.asarray(slot_tok) < T))
+    P = min(E * C, T * K)
+    assert live <= P
+    if case == "capacity_drops":
+        assert live < T * K                  # pairs were dropped
+    ads = rng.integers(0, 4, T).astype(np.int32)
+    ads[:n_off] = -1
+    tok = np.minimum(np.asarray(slot_tok), T - 1)
+    row_adapter = jnp.asarray(np.where(np.asarray(slot_tok) < T, ads[tok],
+                                       -1).astype(np.int32))
+    row_expert = jnp.arange(E * C, dtype=jnp.int32) // C
+    pairs = disagg._live_pairs(slot_tok, jnp.asarray(ads), T, P)
+    # the down hook's rows are nonzero in dead slots too: their delta must
+    # still come out exact 0.0
+    h_rows = jnp.asarray(rng.normal(size=(E * C, cfg.d_ff))
+                         .astype(np.float32))
+    for server in (tr._view, sp.replicas[0]):
+        for hook, rows in (("up", xe.reshape(E * C, -1)), ("down", h_rows)):
+            for layer in range(cfg.n_layers):
+                want = server.compute(hook, layer, rows, row_adapter,
+                                      row_expert)
+                got = disagg._hook_delta(server, hook, layer, rows, pairs, C)
+                np.testing.assert_array_equal(
+                    np.asarray(got).reshape(E, C, -1),
+                    np.asarray(want).reshape(E, C, -1))
+
+
+class _RecordingServer:
+    """The ``compute`` contract, recording how many rows each call asks
+    for; its deltas are zero."""
+
+    def __init__(self, cfg):
+        self.d_out = {"up": 2 * cfg.d_ff, "down": cfg.d_model}
+        self.calls = []
+
+    def compute(self, hook, layer, rows, adapter_ids, expert_ids):
+        import jax.numpy as jnp
+        assert len(adapter_ids) == len(expert_ids) == rows.shape[0]
+        self.calls.append((hook, rows.shape[0]))
+        return jnp.zeros((rows.shape[0], self.d_out[hook]), jnp.float32)
+
+
+@pytest.mark.parametrize("E,K,T,cf", [(8, 2, 1, 1.25), (8, 2, 16, 1.25),
+                                      (16, 8, 4, 1.25), (8, 2, 2049, 0.5)],
+                         ids=["mixtral_T1", "mixtral_T16", "qwen_T4",
+                              "not_dropless"])
+def test_hooks_ask_server_for_live_pair_rows_only(E, K, T, cf):
+    """Each hook call asks the server for min(E*C, T*top_k) rows, not the
+    E*C rows of the dispatch buffer. Dropless decode gives T*top_k; a
+    capacity below T*top_k / E (not dropless) gives E*C."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import disagg
+    from repro.models import model as model_mod
+    cfg = _routing_cfg(E, K, capacity_factor=cf)
+    params = model_mod.init_params(cfg, jax.random.PRNGKey(0),
+                                   dtype="float32")
+    x = jax.random.normal(jax.random.PRNGKey(1), (T, 1, cfg.d_model))
+    rec = _RecordingServer(cfg)
+    disagg._moe_hooks_layer(x, disagg._layer_params(params, 0), cfg, 0, rec,
+                            jnp.zeros(T, jnp.int32), 1.0)
+    C = disagg._capacity(cfg, T)
+    want = min(E * C, T * K)
+    assert want == disagg.hook_rows(cfg, T)
+    assert want == (E * C if cf < 1 else T * K) and want <= E * C
+    assert rec.calls == [("up", want), ("down", want)]
+
+
+def test_hook_rows_counter_reads_rows_per_hook_call(cluster_setup):
+    """With tracing on, each disaggregated engine step samples hook_rows
+    beside decode_bucket: the rows one hook call computes at that bucket,
+    min(E*C, bucket*top_k)."""
+    from repro.core import disagg
+    from repro.serving.api import ServeConfig, build_system
+    cfg, params, pool = cluster_setup
+    sc = ServeConfig(backend="cluster", disaggregated=True, n_instances=1,
+                     max_batch=4, max_len=32, adapter_cache_slots=4,
+                     transport="fused", paged=True, page_size=4, n_pages=16,
+                     prefill_chunk=8, trace=True)
+    system = build_system(sc, cfg, params=params, pool=pool)
+    for a, t, p, o in SPECS:
+        system.submit(adapter_id=a, arrival=t, prompt_len=p,
+                      max_new_tokens=o)
+    system.drain()
+    tr = system.observability().tracer
+    buckets = [(t, v) for k, n, t, v in tr.counters
+               if (k, n) == ("engine", "decode_bucket")]
+    rows = [(t, v) for k, n, t, v in tr.counters
+            if (k, n) == ("engine", "hook_rows")]
+    assert buckets and [t for t, _ in rows] == [t for t, _ in buckets]
+    assert {int(b) for _, b in buckets} >= {1, 2}
+    for (_, b), (_, n) in zip(buckets, rows):
+        assert n == disagg.hook_rows(cfg, int(b)) == int(b) * cfg.top_k
+    system.close()
