@@ -2,19 +2,21 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and holding the longest one,
-is run through the reference (``reference/<module>.py``, float32) over
-each prompt followed by its served tokens. For each served token the gap
-is the reference's best logit at that position minus the reference's
-logit of the served token (0 where the served token is the reference's
-choice). The number compared is the 98th percentile of those gaps over
-the sample (``p98_logit_gap``). The widest gap is printed beside it and
-not compared: in bf16 a top-k router near a tie sends a single token to
-another expert now and then, which moves that token's logits by as much
-as the fp8 control moves them, so the widest gaps of sound runs and of
-the control overlap (PERF.md, Findings). Sound runs hold 0 to 5 such
-tokens in a sample of 400 or more, and fewer than 2.2 % of their tokens
-off the reference's top at all; the control puts 10 to 18 % off it. The
-98th percentile leaves out the few and reads the many.
+is run through the configuration's reference (the module its
+``reference`` key names, float32) on the weights as drawn and placed,
+over each prompt followed by its served tokens. For each served token
+the gap is the reference's best logit at that position minus the
+reference's logit of the served token (0 where the served token is the
+reference's choice). The number compared is the 98th percentile of
+those gaps over the sample (``p98_logit_gap``). The widest gap is
+printed beside it and not compared: in bf16 a top-k router near a tie
+sends a single token to another expert now and then, which moves that
+token's logits by as much as the fp8 control moves them, so the widest
+gaps of sound runs and of the control overlap (PERF.md, Findings). Sound
+runs hold 0 to 5 such tokens in a sample of 400 or more, and fewer than
+2.2 % of their tokens off the reference's top at all; the control puts
+10 to 18 % off it. The 98th percentile leaves out the few and reads the
+many.
 
 The control computes the same reference with fp8 operands (the reference
 module's ``quant="fp8"``) and reads, at the same positions, the gap of
@@ -22,11 +24,11 @@ the token that the control puts first.
 """
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Optional
 
 import numpy as np
 
+import spec
 from harness import Built, Record, Sent
 from e2e import done
 
@@ -52,15 +54,11 @@ def sample(rec: Record, seed: int, min_tokens: int = SAMPLE_TOKENS
     return out
 
 
-def reference_module(conf: Dict):
-    return importlib.import_module(f"reference.{conf['reference']}")
-
-
 def gaps(b: Built, picked: List[Sent], pad_to: int,
          control: bool = False) -> Dict[str, np.ndarray]:
     """Per served position: the program's gap and, with ``control``, the
     control's gap and the control's logits' top token."""
-    ref = reference_module(b.conf)
+    ref = spec.load_module(b.conf["reference"])
     max_out = b.longest["output"]
     served, ctl = [], []
     for s in picked:

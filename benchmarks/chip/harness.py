@@ -83,33 +83,37 @@ class CompileCounter:
 class Built:
     conf: Dict
     system: object
-    params: Dict
-    adapters: Dict
+    params: Dict                  # the drawn weights, as placed, ...
+    adapters: Dict                # ... which the reference reads after
     dims: Dict
     longest: Dict
+    layout: object                # the configuration's layouts/<name>.py
 
 
 def build(conf: Dict, traffic: Dict, seed: int) -> Built:
-    """Weights and adapters from the seed, and one ServeSystem."""
+    """Weights and adapters from the seed, placed where they are served,
+    and one ServeSystem over them."""
     from repro.core.adapter import AdapterPool
     from repro.models.model import abstract_params
     from repro.serving.api import build_system
-    m = spec.model_dims(conf)
+    lay = spec.layout(conf)
+    m = lay.dims(conf)
     longest = workload.longest_request(traffic)
-    cfg = spec.model_config(conf)
+    cfg = lay.model_config(conf)
     params, adapters = weights.make(conf, seed)
     want = jax.tree_util.tree_map(lambda s: (s.shape, str(s.dtype)),
                                   abstract_params(cfg))
     got = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), params)
     if want != got:
-        raise RuntimeError(f"the benchmark's weight layout no longer matches "
-                           f"the program's: {got} vs {want}")
+        raise RuntimeError(f"the program cannot serve layout "
+                           f"{conf['layout']}: its weights are {got}, the "
+                           f"program's {want}")
     pool = AdapterPool(cfg, m["n_adapters"], m["rank"], m["lora_scale"],
                        {t: dict(ab) for t, ab in adapters.items()})
     jax.block_until_ready((params, adapters))
     system = build_system(spec.serve_config(conf, longest), cfg,
                           params=params, pool=pool)
-    return Built(conf, system, params, adapters, m, longest)
+    return Built(conf, system, params, adapters, m, longest, lay)
 
 
 def warm_up(b: Built, seed: int) -> None:

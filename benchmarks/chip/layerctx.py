@@ -6,11 +6,10 @@ there was nothing to read, and the harness then leaves the metric out.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 from typing import Dict, List, Optional
 
 from harness import Record, Round
-from spec import CHIP_DIR
+from spec import load_module
 import trace_reduce
 
 # XLA module of the jitted decode step (transport/fused.py): the
@@ -30,6 +29,7 @@ class LayerContext:
     peak: Dict
     chips: int
     trace: Optional[trace_reduce.Trace] = None
+    layout: object = None         # the configuration's layouts/<name>.py
 
     def traced_rounds(self) -> List[Round]:
         """Decode rounds that ran wholly inside the traced slice."""
@@ -56,11 +56,7 @@ class LayerContext:
 
 
 def load_reader(name: str):
-    path = CHIP_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metrics.{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(f"metrics/{name}.py")
 
 
 def applies(entry: Dict, cell: str, e2e_names) -> bool:

@@ -1,10 +1,10 @@
 """Share of its roofline that the Mosaic paged-attention kernel reaches:
 the least time the chip needs for the kernel's useful bytes and FLOPs
-(counts.paged_attention_work: each live row's real context) over the
-kernel's device time. The work per call is the mean over the decode
+(the layout's paged_attention_work: each live row's real context) over
+the kernel's device time. The work per call is the mean over the decode
 rounds traced; there is one call per layer per decode step."""
-from counts import paged_attention_work, roofline_seconds
 from layerctx import PAGED_KERNEL
+from peaks import roofline_seconds
 
 LAYER = "kernels"
 UNIT = "%"
@@ -17,8 +17,8 @@ def read(ctx):
     per_chip = [evs for evs in ctx.op_events(PAGED_KERNEL) if evs]
     if not rounds or not per_chip:
         return None
-    least = sum(roofline_seconds(*paged_attention_work(ctx.dims, r.contexts),
-                                 ctx.peak) for r in rounds) / len(rounds)
+    least = sum(roofline_seconds(*ctx.layout.paged_attention_work(
+        ctx.dims, r.contexts), ctx.peak) for r in rounds) / len(rounds)
     calls = sum(len(evs) for evs in per_chip) / len(per_chip)
     dev_s = sum(sum(e.dur for e in evs) for evs in per_chip) / len(per_chip)
     return 100.0 * least * calls / dev_s

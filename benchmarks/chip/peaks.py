@@ -22,3 +22,9 @@ def peak(device_kind: str) -> Dict[str, float]:
     except KeyError:
         raise KeyError(f"no published peak for device kind {device_kind!r}; "
                        f"add it to peaks.py with its source") from None
+
+
+def roofline_seconds(flops: float, byts: float, peak: Dict) -> float:
+    """The least time the chip needs: the larger of the compute and the
+    memory bound."""
+    return max(flops / peak["bf16_flops"], byts / peak["hbm_bytes_per_s"])

@@ -68,17 +68,15 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "quant"))
-def _layer(x, lyr, l, ad, adapter, lora_on, dims, quant):
+def attention(x, lyr, dims, quant):
+    """x + the layer's attention block."""
     (d, H, KV, hd, E, K, eps, theta, scale, window) = dims
     S = x.shape[0]
-    take = functools.partial(jax.lax.dynamic_index_in_dim, index=l,
-                             keepdims=False)
     att = lyr["attn"]
-    h = _rms(x, take(lyr["ln1"]), eps)
-    q = _mm(h, take(att["wq"]), quant).reshape(S, H, hd)
-    k = _mm(h, take(att["wk"]), quant).reshape(S, KV, hd)
-    v = _mm(h, take(att["wv"]), quant).reshape(S, KV, hd)
+    h = _rms(x, lyr["ln1"], eps)
+    q = _mm(h, att["wq"], quant).reshape(S, H, hd)
+    k = _mm(h, att["wk"], quant).reshape(S, KV, hd)
+    v = _mm(h, att["wv"], quant).reshape(S, KV, hd)
     pos = jnp.arange(S)
     q, k = _rope(q, pos, theta), _rope(k, pos, theta)
     kv_of = jnp.arange(H) // (H // KV)
@@ -90,31 +88,43 @@ def _layer(x, lyr, l, ad, adapter, lora_on, dims, quant):
     s = jnp.where(mask[None], s, -jnp.inf)
     o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v,
                    precision=HIGHEST).reshape(S, H * hd)
-    x = x + _mm(o, take(att["wo"]), quant)
+    return x + _mm(o, att["wo"], quant)
 
-    moe = lyr["moe"]
-    h = _rms(x, take(lyr["ln2"]), eps)
-    probs = jax.nn.softmax(_mm(h, take(moe["router"]), quant), -1)
+
+def routed_experts(h, moe, ad, lora_on, dims, quant):
+    """The top_k experts' weighted sum over the normed input ``h``, each
+    expert with the request's adapter's deltas."""
+    (d, H, KV, hd, E, K, eps, theta, scale, window) = dims
+    probs = jax.nn.softmax(_mm(h, moe["router"], quant), -1)
     top, ids = jax.lax.top_k(probs, K)
     top = top / jnp.sum(top, -1, keepdims=True)
     p = jnp.sum(jax.nn.one_hot(ids, E, dtype=F32) * top[..., None], 1)
     m = (lora_on.astype(F32) * scale)[:, None]
 
     def lora(t, e, inp):
-        A = ad[t]["A"][l, adapter, e]
-        B = ad[t]["B"][l, adapter, e]
+        A = ad[t]["A"][e]
+        B = ad[t]["B"][e]
         return m * _mm(_mm(inp, A, quant), B, quant)
 
     def expert(acc, e):
-        wg, wu, wd = moe["gate"][l, e], moe["up"][l, e], moe["down"][l, e]
+        wg, wu, wd = moe["gate"][e], moe["up"][e], moe["down"][e]
         g = _mm(h, wg, quant) + lora("gate", e, h)
         u = _mm(h, wu, quant) + lora("up", e, h)
         a = jax.nn.silu(g) * u
         y = _mm(a, wd, quant) + lora("down", e, a)
         return acc + jnp.take(p, e, axis=1)[:, None] * y, None
 
-    y, _ = jax.lax.scan(expert, jnp.zeros_like(x), jnp.arange(E))
-    return x + y
+    y, _ = jax.lax.scan(expert, jnp.zeros_like(h), jnp.arange(E))
+    return y
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "quant"))
+def _layer(x, lyr, ad, lora_on, dims, quant):
+    """One layer, given its own weights and the request's adapter's
+    factors for it (``ad[t]["A"]`` is (E, d_in, r))."""
+    x = attention(x, lyr, dims, quant)
+    h = _rms(x, lyr["ln2"], dims[6])
+    return x + routed_experts(h, lyr["moe"], ad, lora_on, dims, quant)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "vocab", "quant"))
@@ -132,22 +142,42 @@ def dims_key(m: Dict):
             m["lora_scale"], m["window"])
 
 
+@jax.jit
+def _index(tree, *idx):
+    """Each leaf of ``tree`` at the leading indices ``idx``, placed as the
+    leaf is."""
+    return jax.tree_util.tree_map(lambda a: a[idx], tree)
+
+
+def _here(tree):
+    """``tree``'s arrays on the first device: a leaf placed over several
+    chips is gathered there. Callers hand over one layer at a time, so
+    that the reference never holds more than one whole layer on a chip
+    beside the weights as placed."""
+    dev = jax.devices()[0]
+    return jax.tree_util.tree_map(lambda a: jax.device_put(a, dev), tree)
+
+
 def logits(params: Dict, adapters: Dict, m: Dict, tokens: np.ndarray,
            lora_from: int, adapter: int, rows: np.ndarray, *, pad_to: int,
-           quant: Optional[str] = None) -> np.ndarray:
+           quant: Optional[str] = None, layer=_layer) -> np.ndarray:
     """Float32 logits (len(rows), vocab) at positions ``rows`` of the
     sequence ``tokens``, with the adapter applied from position
     ``lora_from`` on. The sequence is zero-padded to ``pad_to`` positions
-    at the end, which no earlier position sees."""
+    at the end, which no earlier position sees. The weights may lie on
+    one device or be placed over a mesh; each layer is read whole on the
+    first device, in turn, and computed by ``layer`` (a reference built
+    on this one passes its own)."""
     S = len(tokens)
     tok = np.zeros(pad_to, np.int32)
     tok[:S] = tokens
-    x = params["embed"][jnp.asarray(tok)].astype(F32)
+    x = _here(params["embed"])[jnp.asarray(tok)].astype(F32)
     lora_on = jnp.asarray(np.arange(pad_to) >= lora_from)
     dk = dims_key(m)
     for l in range(m["n_layers"]):
-        x = _layer(x, params["layers"], jnp.int32(l), adapters,
-                   jnp.int32(adapter), lora_on, dk, quant)
-    out = _head(x, jnp.asarray(rows, jnp.int32), params["final_norm"],
-                params["lm_head"], m["norm_eps"], m["vocab"], quant)
+        lyr = _here(_index(params["layers"], jnp.int32(l)))
+        ad = _here(_index(adapters, jnp.int32(l), jnp.int32(adapter)))
+        x = layer(x, lyr, ad, lora_on, dk, quant)
+    out = _head(x, jnp.asarray(rows, jnp.int32), _here(params["final_norm"]),
+                _here(params["lm_head"]), m["norm_eps"], m["vocab"], quant)
     return np.asarray(out)

@@ -147,7 +147,8 @@ def execute(args, check_device=True):
         import trace_reduce
         path = trace_reduce.find_xplane(trace_dir)
         tr = trace_reduce.load(path) if path else None
-        ctx = layerctx.LayerContext(rec, b.dims, peak, chips, tr)
+        ctx = layerctx.LayerContext(rec, b.dims, peak, chips, tr,
+                                    b.layout)
         metrics = layer_metrics(bench, args.workload, ctx)
         if tr is not None and tr.devices:
             lo, hi = trace_reduce.window(tr)

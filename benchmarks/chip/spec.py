@@ -3,7 +3,13 @@ the program's ``ModelConfig`` and ``ServeConfig``.
 
   BENCHMARK.json (checkout root)  cells, metrics, bounds
   configs/<config>.json           sizes as run, the published config
-                                  beside them, cuts, departures
+                                  beside them, cuts, departures; its
+                                  ``layout`` and ``reference`` name the
+                                  modules below, as paths under this
+                                  directory
+  layouts/<layout>.py             one parameter set: dims, ModelConfig,
+                                  leaves and their placement, useful work
+  reference/<layout>.py           its plain float32 reference
   traffic/<traffic>.json          one traffic mix (see workload.py)
   cells/<cell>.json               the cell's offered rate, knee sweep and
                                   correctness limits
@@ -11,8 +17,10 @@ the program's ``ModelConfig`` and ``ServeConfig``.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import pathlib
+import sys
 from typing import Dict, Optional, Tuple
 
 CHIP_DIR = pathlib.Path(__file__).resolve().parent
@@ -47,38 +55,27 @@ def load_cell(name: str) -> Dict:
     return _read(CHIP_DIR / "cells" / f"{name}.json")
 
 
-def model_dims(conf: Dict) -> Dict:
-    """The published keys that the harness, the counts and the reference
-    read, under one set of names."""
-    c = conf["config"]
-    d, H = c["hidden_size"], c["num_attention_heads"]
-    return {
-        "d_model": d, "n_heads": H, "n_kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim") or d // H,
-        "d_ff": c.get("moe_intermediate_size") or c["intermediate_size"],
-        "n_experts": c.get("num_local_experts") or c["num_experts"],
-        "top_k": c["num_experts_per_tok"], "n_layers": c["num_hidden_layers"],
-        "vocab": c["vocab_size"], "rope_theta": float(c["rope_theta"]),
-        "norm_eps": float(c["rms_norm_eps"]),
-        "window": int(c.get("sliding_window") or 0),
-        "rank": conf["lora"]["rank"],
-        "lora_scale": conf["lora"]["alpha"] / conf["lora"]["rank"],
-        "n_adapters": conf["lora"]["n_adapters"],
-    }
+def load_module(rel: str):
+    """The module at ``rel``, a path under the benchmark's directory: a
+    configuration's layout and reference, a traffic kind's generator, a
+    per-layer metric's reader. Loaded once per process."""
+    path = CHIP_DIR / rel
+    name = "bench." + rel.removesuffix(".py").replace("/", ".")
+    if name not in sys.modules:
+        if not path.is_file():
+            raise FileNotFoundError(f"no module at {path}")
+        found = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(found)
+        sys.modules[name] = mod
+        found.loader.exec_module(mod)
+    return sys.modules[name]
 
 
-def model_config(conf: Dict):
-    """The program's ModelConfig for this configuration file."""
-    from repro.configs.base import ModelConfig
-    m = model_dims(conf)
-    return ModelConfig(
-        name=conf["name"], family="moe", n_layers=m["n_layers"],
-        d_model=m["d_model"], n_heads=m["n_heads"],
-        n_kv_heads=m["n_kv_heads"], head_dim=m["head_dim"], d_ff=m["d_ff"],
-        vocab_size=m["vocab"], rope_theta=m["rope_theta"],
-        norm_eps=m["norm_eps"], sliding_window=m["window"],
-        n_experts=m["n_experts"], top_k=m["top_k"], lora_rank=m["rank"],
-        lora_targets=tuple(conf["lora"]["targets"]), dtype="bfloat16")
+def layout(conf: Dict):
+    """The configuration's layout module (``layouts/<name>.py``): its
+    dims, the program's ModelConfig, the leaves and their placement, and
+    the useful work of a decode step."""
+    return load_module(conf["layout"])
 
 
 def max_len(conf: Dict, longest: Dict[str, int]) -> int:

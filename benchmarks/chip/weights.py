@@ -1,36 +1,26 @@
 """The benchmark's weights and adapters, drawn on the device from the seed
-in one compiled call, in the type they are served in (bfloat16).
+in one compiled call, in the type they are served in (bfloat16), each
+leaf where it is served.
 
-Layout (shared by the program and the reference; the benchmark owns it):
-
-  embed, lm_head         (V_pad, d)     V_pad = vocab rounded up to 256
-  final_norm             (d,)           stored as gamma - 1
-  layers.ln1, ln2        (L, d)         stored as gamma - 1
-  layers.attn.wq         (L, d, H*hd)   wk, wv (L, d, KV*hd); wo (L, H*hd, d)
-  layers.moe.router      (L, d, E)
-  layers.moe.gate, up    (L, E, d, ff); down (L, E, ff, d)
-  adapters[t].A, .B      t in gate/up: (L, N, E, d, r), (L, N, E, r, ff);
-                         down: (L, N, E, ff, r), (L, N, E, r, d)
-
-Each matrix is normal with standard deviation gain / sqrt(fan_in), the
-gains taken from the configuration's ``init`` block.
+The configuration's layout (``layouts/<name>.py``) gives the leaves, in
+the order they are drawn, and their placement: with ``serve.mesh_shape``
+set, a leaf's expert dimension lies along the mesh's expert axis and
+every other leaf is on every chip, so that no chip ever holds more than
+its share; without a mesh everything is on the default device. Leaf
+``i`` is drawn from ``fold_in(key, i)`` by JAX's partitionable threefry,
+so its bits do not depend on where it is placed.
 """
 from __future__ import annotations
 
-import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spec import model_dims
+import spec
 
 BF16 = jnp.bfloat16
-
-
-def padded_vocab(vocab: int) -> int:
-    return -(-vocab // 256) * 256
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -39,35 +29,6 @@ def seed_key(seed: int) -> jax.Array:
     for word in (seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF):
         key = jax.random.fold_in(key, jnp.asarray(np.uint32(word)))
     return key
-
-
-def shapes(m: Dict) -> Tuple[Dict, Dict]:
-    """{path: (shape, fan_in, gain_key)} of the weights and the adapters."""
-    d, H, KV, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    L, E, ff, r, N = (m["n_layers"], m["n_experts"], m["d_ff"], m["rank"],
-                      m["n_adapters"])
-    V = padded_vocab(m["vocab"])
-    w = {
-        "embed": ((V, d), None, "embed_std"),
-        "lm_head": ((V, d), d, "lm_head_gain"),
-        "final_norm": ((d,), None, "norm_std"),
-        "layers.ln1": ((L, d), None, "norm_std"),
-        "layers.ln2": ((L, d), None, "norm_std"),
-        "layers.attn.wq": ((L, d, H * hd), d, "attn_gain"),
-        "layers.attn.wk": ((L, d, KV * hd), d, "attn_gain"),
-        "layers.attn.wv": ((L, d, KV * hd), d, "attn_gain"),
-        "layers.attn.wo": ((L, H * hd, d), H * hd, "attn_out_gain"),
-        "layers.moe.router": ((L, d, E), d, "router_gain"),
-        "layers.moe.gate": ((L, E, d, ff), d, "expert_in_gain"),
-        "layers.moe.up": ((L, E, d, ff), d, "expert_in_gain"),
-        "layers.moe.down": ((L, E, ff, d), ff, "expert_out_gain"),
-    }
-    a = {}
-    for t, (din, dout) in (("gate", (d, ff)), ("up", (d, ff)),
-                           ("down", (ff, d))):
-        a[f"{t}.A"] = ((L, N, E, din, r), din, "lora_a_gain")
-        a[f"{t}.B"] = ((L, N, E, r, dout), r, "lora_b_gain")
-    return w, a
 
 
 def nest(flat: Dict) -> Dict:
@@ -81,7 +42,44 @@ def nest(flat: Dict) -> Dict:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("spec",))
+def leaves(conf: Dict) -> Dict:
+    """{path: (shape, fan_in, gain_key)} of every leaf in drawing order,
+    the adapters' under ``adapters.``."""
+    lay = spec.layout(conf)
+    w, a = lay.shapes(lay.dims(conf))
+    return {**w, **{"adapters." + k: v for k, v in a.items()}}
+
+
+def mesh_of(conf: Dict) -> Optional[jax.sharding.Mesh]:
+    """The serving mesh of ``serve.mesh_shape``: the first data x model
+    devices, axes ("data", "model"), as the program lays them out."""
+    shape = conf["serve"].get("mesh_shape")
+    if not shape:
+        return None
+    data, model = shape
+    devs = jax.devices()
+    if data * model > len(devs):
+        raise RuntimeError(f"mesh_shape {shape} needs {data * model} "
+                           f"devices, {len(devs)} visible")
+    return jax.sharding.Mesh(
+        np.asarray(devs[:data * model]).reshape(data, model),
+        ("data", "model"))
+
+
+def partition_specs(conf: Dict) -> Dict[str, jax.sharding.PartitionSpec]:
+    """{path: PartitionSpec} of every leaf on the configuration's mesh."""
+    lay = spec.layout(conf)
+    axis = lay.expert_axis(lay.dims(conf), conf["serve"]["mesh_shape"])
+    out = {}
+    for path, (shape, _, _) in leaves(conf).items():
+        dim = lay.expert_dim(path) if axis else None
+        parts = [None] * len(shape)
+        if dim is not None:
+            parts[dim] = axis
+        out[path] = jax.sharding.PartitionSpec(*parts)
+    return out
+
+
 def _draw(key, spec):
     out = {}
     for i, (path, shape, std) in enumerate(spec):
@@ -90,17 +88,45 @@ def _draw(key, spec):
     return out
 
 
+_draw_here = jax.jit(_draw, static_argnames=("spec",))
+
+
 def make(conf: Dict, seed: int) -> Tuple[Dict, Dict]:
-    """(params, adapters) on the default device, from ``seed``."""
-    m = model_dims(conf)
+    """(params, adapters) from ``seed``, each leaf in its placement."""
     init = conf["init"]
-    w, a = shapes(m)
-    spec = []
-    for path, (shape, fan_in, gain) in list(w.items()) + [
-            ("adapters." + k, v) for k, v in a.items()]:
+    spec_ = []
+    for path, (shape, fan_in, gain) in leaves(conf).items():
         std = init[gain] if fan_in is None else init[gain] / np.sqrt(fan_in)
-        spec.append((path, tuple(shape), float(std)))
-    flat = _draw(seed_key(seed), tuple(spec))
-    tree = nest(flat)
+        spec_.append((path, tuple(shape), float(std)))
+    mesh = mesh_of(conf)
+    draw = _draw_here if mesh is None else jax.jit(
+        _draw, static_argnames=("spec",), out_shardings={
+            path: jax.sharding.NamedSharding(mesh, ps)
+            for path, ps in partition_specs(conf).items()})
+    tree = nest(draw(seed_key(seed), tuple(spec_)))
     return {k: v for k, v in tree.items() if k != "adapters"}, \
         tree["adapters"]
+
+
+@jax.jit
+def _leaf_sum(x):
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint16).reshape(-1)
+    w = jax.lax.iota(jnp.uint32, bits.size) % jnp.uint32(65521) + 1
+    return jnp.sum(bits.astype(jnp.uint32) * w, dtype=jnp.uint32)
+
+
+def flatten(params: Dict, adapters: Optional[Dict] = None
+            ) -> Dict[str, jax.Array]:
+    """{path: leaf} of a draw, under the paths of ``leaves``."""
+    out = {}
+    for prefix, tree in (("", params), ("adapters.", adapters or {})):
+        for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
+            out[prefix + ".".join(str(k.key) for k in path)] = x
+    return out
+
+
+def checksum(params: Dict, adapters: Dict) -> Dict[str, int]:
+    """{path: 32-bit checksum of a bfloat16 leaf's bits}: each 16-bit word
+    times its flat index mod 65521, plus 1, summed modulo 2**32."""
+    return {k: int(_leaf_sum(x))
+            for k, x in flatten(params, adapters).items()}

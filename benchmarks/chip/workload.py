@@ -4,11 +4,10 @@ function). A new mix of an existing kind is a new data file only."""
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 from typing import Dict, List
 
-from spec import CHIP_DIR
+from spec import CHIP_DIR, load_module
 
 
 @dataclasses.dataclass
@@ -25,13 +24,7 @@ def load_traffic(name: str) -> Dict:
 
 
 def _generator(kind: str):
-    path = CHIP_DIR / "generators" / f"{kind}.py"
-    if not path.is_file():
-        raise ValueError(f"traffic kind {kind!r} has no generator at {path}")
-    spec = importlib.util.spec_from_file_location(f"generators.{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.generate
+    return load_module(f"generators/{kind}.py").generate
 
 
 def generate(traffic: Dict, *, rate: float, seconds: float, seed: int,
